@@ -38,6 +38,9 @@ def main() -> None:
     # (protocol/fleet identical to the paper; --full restores paper scale)
     rounds = args.rounds or (6 if args.quick else (200 if args.full else 8))
     only = set(args.only.split(",")) if args.only else None
+    from repro.kernels.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     def want(name):
         return only is None or name in only

@@ -3,7 +3,8 @@
 One round = (1) server allocation [blue]: EMA divergence -> Eq. 7 budgets ->
 top-k group selection; (2) parallel local training [green]: clients run E
 epochs with gradients gated to their assigned groups (vmapped over the client
-axis — on a TPU mesh this axis is sharded, see dist/); (3) server aggregation
+axis in slices of ``CLIENT_CHUNK`` — on a TPU mesh this axis is sharded, see
+dist/); (3) server aggregation
 [orange]: cohort-wise masked means (Eq. 3-4) + divergence update (Eq. 5-6).
 
 Fault tolerance: client participation is a per-round mask — any dropout
@@ -32,6 +33,12 @@ from repro.sim import FleetConfig
 from repro.sim import timing as T
 
 Array = jax.Array
+
+# clients trained side by side (vmapped) per slice of a local-update call;
+# larger cohorts run as a scan over slices. Bounds device memory: at PAMAP2
+# Backbone 2 widths one client's training step keeps ~0.3 GB of TPU-padded
+# activations live, so a 64-client flush at once would not fit a 16 GB chip.
+CLIENT_CHUNK = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,7 +121,12 @@ def make_local_update(task: MMTask, fed: FedConfig, prox_mu: float):
         delta = jax.tree.map(lambda d, m: d * m, delta, rank_gate)
         return delta, jnp.mean(losses)
 
-    return jax.jit(jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0, None)))
+    def clients(start, batches, mmask, gate, rank_gate, lr):
+        return jax.lax.map(lambda c: one_client(*c, lr),
+                           (start, batches, mmask, gate, rank_gate),
+                           batch_size=CLIENT_CHUNK)
+
+    return jax.jit(clients)
 
 
 # ---------------------------------------------------------------------------

@@ -3,19 +3,21 @@
 The kernel tiles the row dimension D of the fusion leaf into ``bd``-row
 blocks; N streams innermost so the four accumulators stay VMEM-resident.
 The right ``bd`` balances per-step DMA size against grid overhead and is
-shape- and backend-dependent, so instead of the historical hardcoded 256
-the wrappers resolve ``bd=None`` here, once per shape (process-cached):
+shape- and backend-dependent, so instead of a hardcoded value the wrappers
+resolve ``bd=None`` here, once per shape (process-cached):
 
 * interpret mode / XLA impl: timing is meaningless (interpret) or unused
-  (the einsum oracle ignores ``bd``), so take the largest divisor of D
+  (the einsum oracle ignores ``bd``), so take the largest legal block
   within the VMEM accumulator budget — the fewest-launches heuristic.
 * compiled Pallas (real TPU/GPU backend): run a bench_roofline.py-style
   sweep over the candidate cells on dummy data and keep the fastest
   (median of ``_SWEEP_REPS`` timed reps after a compile warm-up).
 
-``largest_divisor`` is also the one-stop fix for non-divisible shapes: any
-requested ``bd`` is snapped down to the largest divisor of D that does not
-exceed it, so blocking never silently degenerates to a single D-row tile.
+Every candidate is a tiling the TPU compiler accepts (kernels/runtime.py
+``legal_tile``): a multiple of 128 that divides the axis, or the whole
+axis — so a dim with no such divisor (hymba's d_model = 1600) has exactly
+one candidate, the full dim. An explicit block size snaps to the largest
+legal one that does not exceed it.
 """
 from __future__ import annotations
 
@@ -25,8 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# power-of-two cells the sweep considers (snapped to divisors of D)
-_CANDIDATE_CAPS = (64, 128, 256, 512)
+from repro.kernels.runtime import legal_tile
+
+# power-of-two caps the sweep considers (snapped to legal tiles)
+_CANDIDATE_CAPS = (128, 256, 512)
 _SWEEP_REPS = 3
 # accumulators are 2*(bd*r + bd) f32 plus the streamed (bd, r) input tile;
 # stay well under the ~16 MB/core VMEM so double buffering has headroom
@@ -35,22 +39,13 @@ _VMEM_ACC_BUDGET = 4 * 2**20
 _CACHE: dict[tuple, int] = {}
 
 
-def largest_divisor(D: int, cap: int) -> int:
-    """Largest divisor of D that is <= cap (>= 1)."""
-    b = max(1, min(int(cap), int(D)))
-    while D % b:
-        b -= 1
-    return b
-
-
 def candidate_bds(D: int, r: int) -> list[int]:
-    """Distinct, VMEM-feasible candidate block sizes for row dimension D."""
-    cands = set()
-    for cap in _CANDIDATE_CAPS:
-        bd = largest_divisor(D, cap)
-        if 4 * (2 * bd * (r + 1) + bd * r) <= _VMEM_ACC_BUDGET:
-            cands.add(bd)
-    return sorted(cands) or [1]
+    """Distinct legal block sizes for row dimension D, VMEM-feasible ones
+    first; the smallest legal block when none fits the budget."""
+    legal = sorted({legal_tile(D, cap) for cap in _CANDIDATE_CAPS})
+    fits = [bd for bd in legal
+            if 4 * (2 * bd * (r + 1) + bd * r) <= _VMEM_ACC_BUDGET]
+    return fits or legal[:1]
 
 
 def clear_cache() -> None:
@@ -127,14 +122,16 @@ def _mdlora_vmem_bytes(bt: int, bf: int, bd: int, r: int) -> int:
 
 def mdlora_candidates(T: int, D: int, F: int, r: int,
                       multi: bool) -> list[tuple[int, int, int]]:
-    """Distinct VMEM-feasible (bt, bf, bd) cells (bt = 1 when ``multi``)."""
-    cands = set()
+    """Distinct legal (bt, bf, bd) cells (bt = 1 when ``multi``), the
+    VMEM-feasible ones; the smallest legal cell when none fits."""
+    cells = set()
     for cap in _CANDIDATE_CAPS:
-        bt = 1 if multi else largest_divisor(T, cap)
-        bf, bd = largest_divisor(F, cap), largest_divisor(D, cap)
-        if _mdlora_vmem_bytes(bt, bf, bd, r) <= _VMEM_ACC_BUDGET:
-            cands.add((bt, bf, bd))
-    return sorted(cands) or [(1, largest_divisor(F, 1), largest_divisor(D, 1))]
+        # bt: multiple of the bf16 sublane tile (16) or all of T
+        bt = 1 if multi else legal_tile(T, cap, 16)
+        cells.add((bt, legal_tile(F, cap), legal_tile(D, cap)))
+    cells = sorted(cells)
+    fits = [c for c in cells if _mdlora_vmem_bytes(*c, r) <= _VMEM_ACC_BUDGET]
+    return fits or cells[:1]
 
 
 def select_mdlora_blocks(shape: tuple[int, int, int, int],

@@ -4,7 +4,8 @@
 see kernels/runtime.py), so ``impl="pallas"`` is safe everywhere without the
 caller knowing the hardware. ``bd=None`` resolves through the autotuner
 (kernels/cohort_agg/autotune.py) at trace time; an explicit ``bd`` is
-snapped to the largest divisor of D that does not exceed it.
+snapped to the largest legal TPU block of D that does not exceed it
+(kernels/runtime.py ``legal_tile``).
 """
 from __future__ import annotations
 
@@ -12,12 +13,12 @@ import functools
 
 import jax
 
-from repro.kernels.cohort_agg.autotune import largest_divisor, select_block_size
+from repro.kernels.cohort_agg.autotune import select_block_size
 from repro.kernels.cohort_agg.kernel import (
     cohort_agg_divergence_pallas, cohort_agg_divergence_quant_pallas)
 from repro.kernels.cohort_agg.ref import (cohort_agg_divergence_quant_ref,
                                           cohort_agg_divergence_ref)
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import legal_tile, resolve_interpret
 
 
 def _resolve_bd(shape, impl: str, interpret: bool, bd: int | None,
@@ -25,7 +26,7 @@ def _resolve_bd(shape, impl: str, interpret: bool, bd: int | None,
     if bd is None:
         return select_block_size(shape, impl=impl, interpret=interpret,
                                  quant=quant)
-    return largest_divisor(shape[1], bd)
+    return legal_tile(shape[1], bd)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "interpret", "bd"))

@@ -4,8 +4,9 @@
 see kernels/runtime.py). Block sizes default to ``None`` and resolve through
 the shared autotuner (kernels/cohort_agg/autotune.py): largest-divisor
 heuristic on interpret/XLA backends, timed sweep on compiled Pallas.
-Explicit block sizes are snapped to the largest divisor of the tiled axis,
-so blocking survives non-divisible shapes.
+Explicit block sizes are snapped to the largest legal TPU block of the
+tiled axis (kernels/runtime.py ``legal_tile``), so blocking survives
+non-divisible shapes.
 """
 from __future__ import annotations
 
@@ -15,13 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cohort_agg.autotune import (largest_divisor,
-                                               select_mdlora_blocks)
+from repro.kernels.cohort_agg.autotune import select_mdlora_blocks
 from repro.kernels.mdlora.kernel import (mdlora_matmul_multi_pallas,
                                          mdlora_matmul_pallas)
 from repro.kernels.mdlora.ref import (mdlora_matmul_multi_ref,
                                       mdlora_matmul_ref)
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import legal_tile, resolve_interpret
 
 
 def block_row_mask(block_dims, modality_mask) -> jnp.ndarray:
@@ -46,8 +46,8 @@ def _resolve_blocks(T, D, F, r, impl, interpret, bt, bf, bd, multi=False,
                                           interpret=interpret, multi=multi,
                                           n_adapters=n_adapters)
         bt, bf, bd = bt or tt, bf or tf, bd or td
-    return (1 if multi else largest_divisor(T, bt), largest_divisor(F, bf),
-            largest_divisor(D, bd))
+    return (1 if multi else legal_tile(T, bt, 16), legal_tile(F, bf),
+            legal_tile(D, bd))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "impl", "interpret",

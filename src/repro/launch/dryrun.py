@@ -1,6 +1,13 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 extract memory/cost/collective evidence for EXPERIMENTS.md §Dry-run/§Roofline.
 
+A CPU-only rehearsal tool: it pins JAX to the CPU platform itself and asks
+the host platform for 512 virtual devices, so the 16x16 single-pod and
+2x16x16 multi-pod production meshes can be built and compiled on any
+machine — including one with a TPU attached, which this tool never touches.
+Compiling for a real chip is done by describing its topology instead
+(tests/test_tpu_compile.py); running on one is chip_smoke.py.
+
 Usage:
   python -m repro.launch.dryrun --arch phi3-medium-14b --shape train_4k
   python -m repro.launch.dryrun --arch all --shape all [--multi-pod] \
@@ -11,10 +18,13 @@ skipped (the full grid is resumable after interruption — the same mechanism
 a real cluster launcher uses for preemption tolerance).
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every jax import: jax locks the device count on first init.
-# The 512 host devices exist ONLY for this dry-run (16x16 single-pod and
-# 2x16x16 multi-pod production meshes); tests and benches see 1 device.
+
+# Both MUST precede every jax import: jax fixes the platform and the host
+# device count on first initialisation. Any XLA_FLAGS already set are kept.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS", ""),
+    "--xla_force_host_platform_device_count=512")))
 
 import argparse
 import dataclasses
@@ -182,8 +192,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t1 = time.time()
         compiled = _compile_step(pcfg, mod, shape, mesh, train_mode)
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jaxlib<0.4.38 returns per-device
-            ca = ca[0] if ca else {}
         hlo = compiled.as_text()
         coll = RL.parse_collectives(hlo)
         probe_stats.append({
@@ -245,6 +253,9 @@ def main():
                     help="compile+memory only (multi-pod shardability pass)")
     args = ap.parse_args()
 
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     from repro.configs import base
 
     archs = base.list_archs() if args.arch == "all" else [args.arch]

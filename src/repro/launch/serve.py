@@ -23,7 +23,12 @@ import argparse
 import time
 
 
-def _run_engine(args, cfg):
+def build_engine(cfg, *, n_adapters: int, batch: int, prompt_len: int,
+                 decode_steps: int, seed: int = 0):
+    """The personalized-serving setup: random base weights, ``n_adapters``
+    client adapters with random modality masks, a ``batch``-slot engine and
+    2x ``batch`` requests (slots recycle), prompts of prompt_len/2..prompt_len
+    tokens. -> (engine, requests); nothing is submitted yet."""
     import jax
     import numpy as np
 
@@ -31,28 +36,33 @@ def _run_engine(args, cfg):
                                              ServingEngine)
     from repro.models import api
 
-    key = jax.random.PRNGKey(args.seed)
-    params = api.init_model(key, cfg)
-    rng = np.random.default_rng(args.seed)
-    reg = AdapterRegistry(jax.random.PRNGKey(1), cfg,
-                          capacity=args.n_adapters)
+    params = api.init_model(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed)
+    reg = AdapterRegistry(jax.random.PRNGKey(1), cfg, capacity=n_adapters)
     n_blocks = len(reg.block_dims)
-    for i in range(args.n_adapters):
+    for i in range(n_adapters):
         lora = api.init_model(jax.random.PRNGKey(100 + i), cfg)["lora"]
         mm = (rng.random(n_blocks) < 0.8).astype(np.float32)
         mm[int(rng.integers(n_blocks))] = 1.0  # >=1 modality present
         reg.register(f"client-{i}", lora, modality_mask=mm)
 
-    max_len = args.prompt_len + args.decode_steps + 2
-    eng = ServingEngine(params, cfg, reg, batch_slots=args.batch,
-                        max_len=max_len)
-    for r in range(args.batch * 2):  # 2x oversubscribed: slots recycle
-        plen = int(rng.integers(max(2, args.prompt_len // 2),
-                                args.prompt_len + 1))
-        eng.submit(Request(
+    max_len = prompt_len + decode_steps + 2
+    eng = ServingEngine(params, cfg, reg, batch_slots=batch, max_len=max_len)
+    reqs = []
+    for r in range(batch * 2):  # 2x oversubscribed: slots recycle
+        plen = int(rng.integers(max(2, prompt_len // 2), prompt_len + 1))
+        reqs.append(Request(
             rid=f"req-{r}", prompt=rng.integers(0, cfg.vocab, plen),
-            adapter=f"client-{r % args.n_adapters}",
-            max_new_tokens=args.decode_steps))
+            adapter=f"client-{r % n_adapters}", max_new_tokens=decode_steps))
+    return eng, reqs
+
+
+def _run_engine(args, cfg):
+    eng, reqs = build_engine(cfg, n_adapters=args.n_adapters,
+                             batch=args.batch, prompt_len=args.prompt_len,
+                             decode_steps=args.decode_steps, seed=args.seed)
+    for req in reqs:
+        eng.submit(req)
     res = eng.run()
     print(f"[serve/engine] {args.arch}: {len(res['outputs'])} requests, "
           f"{res['generated_tokens']} tokens in {res['wall_s']:.2f}s "
@@ -78,6 +88,10 @@ def main():
 
     import jax
     import jax.numpy as jnp
+
+    from repro.kernels.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import base
     from repro.launch import step_fns as SF

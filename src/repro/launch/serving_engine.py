@@ -196,11 +196,20 @@ class ServingEngine:
     scattered into the shared cache; (2) one jitted batched decode step
     advances all active rows, each applying its own adapter via the
     gathered mdlora kernel. Finished rows are recycled immediately.
+
+    ``lora_impl`` picks the gathered projection: "pallas" (the fused
+    kernel) or "xla" (its einsum oracle). None takes the kernel wherever it
+    compiles and the oracle on the CPU, where the kernel only interprets.
     """
 
     def __init__(self, params: dict, cfg: ModelConfig,
                  registry: AdapterRegistry, batch_slots: int, max_len: int,
-                 lora_impl: str = "xla"):
+                 lora_impl: str | None = None):
+        from repro.kernels.runtime import default_interpret
+
+        if lora_impl is None:
+            lora_impl = "xla" if default_interpret() else "pallas"
+        self.lora_impl = lora_impl
         self.cfg = cfg
         self.registry = registry
         self.B = batch_slots

@@ -15,68 +15,103 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
-def train_backbone(args):
+def run_backbone(cfg, mesh, *, steps: int, batch: int, seq: int,
+                 lr: float = 1e-3, train_mode: str = "lora", seed: int = 0,
+                 strategy: str | None = None, log_every: int = 10,
+                 ckpt=None, ckpt_every: int = 25) -> list[float]:
+    """Train ``cfg`` on synthetic token streams over ``mesh``; -> losses.
+
+    Params, optimizer state and batches are placed with the
+    ``dist.sharding`` specs of ``strategy`` (default: ``pick_strategy`` for
+    training this config) and the jitted step keeps them there. ``ckpt``
+    (a CheckpointManager, or None) resumes from and saves to a directory.
+    """
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    from repro.checkpoint import CheckpointManager
-    from repro.configs import base
     from repro.data.tokens import synthetic_token_batches
     from repro.dist import sharding as SH
     from repro.launch import step_fns as SF
-    from repro.launch.mesh import make_host_mesh
+    from repro.models import api
     from repro.optim import adam_init
+
+    strategy = strategy or SH.pick_strategy(cfg, "train")
+    batch_axes = SH.data_axes(mesh)
+    if strategy in ("fsdp", "replicated") and "model" in mesh.axis_names:
+        batch_axes += ("model",)  # every chip carries examples (batch_specs)
+    SH.set_activation_mesh(mesh, batch_axes=batch_axes,
+                           tp=(strategy == "tp"))
+
+    params = api.init_model(jax.random.PRNGKey(seed), cfg)
+    tr, _ = SF.split_trainable(params, train_mode)
+    opt = adam_init(tr)
+    start_step = 0
+    if ckpt is not None:
+        restored = ckpt.restore_latest({"params": params, "opt": opt})
+        if restored is not None:
+            (state, meta) = restored
+            params, opt = state["params"], state["opt"]
+            start_step = meta["step"]
+            print(f"[train] resumed from step {start_step}")
+
+    shard = lambda t: SH.to_named(mesh, t)  # noqa: E731
+    pspec = SH.param_specs(cfg, params, mesh, train=True, strategy=strategy)
+    ospec = SH.opt_state_specs(
+        pspec["lora"] if train_mode == "lora" else pspec, opt, mesh)
+    params = jax.device_put(params, shard(pspec))
+    opt = jax.device_put(opt, shard(ospec))
+    batches = synthetic_token_batches(cfg.vocab, batch, seq, steps, seed=seed,
+                                      n_codebooks=cfg.n_codebooks)
+    bshard = None
+    jit_step = None
+    losses = []
+    t0 = time.time()
+    with mesh:
+        for i, b in enumerate(batches):
+            step = start_step + i
+            b = {k: jnp.asarray(v) for k, v in b.items()}
+            if cfg.family == "vlm":
+                b["patches"] = jnp.zeros((batch, cfg.n_patches, cfg.d_model),
+                                         cfg.runtime_dtype())
+            if jit_step is None:
+                bshard = shard(SH.batch_specs(b, mesh, cfg, strategy))
+                jit_step = jax.jit(
+                    SF.make_train_step(cfg, lr=lr, train_mode=train_mode),
+                    in_shardings=(shard(pspec), shard(ospec), bshard),
+                    out_shardings=(shard(pspec), shard(ospec), None),
+                    donate_argnums=(0, 1))
+            params, opt, metrics = jit_step(params, opt,
+                                            jax.device_put(b, bshard))
+            losses.append(float(metrics["loss"]))
+            if log_every and (step + 1) % log_every == 0:
+                print(f"[train] step {step+1} loss {losses[-1]:.4f} "
+                      f"({(time.time()-t0)/(i+1):.2f}s/step)")
+            if ckpt is not None and (step + 1) % ckpt_every == 0:
+                ckpt.save(step + 1, {"params": params, "opt": opt},
+                          {"arch": cfg.arch})
+    SH.set_activation_mesh(None)
+    return losses
+
+
+def train_backbone(args):
+    from repro.checkpoint import CheckpointManager
+    from repro.configs import base
+    from repro.launch.mesh import make_host_mesh
 
     mod = base.get_arch(args.arch)
     cfg = mod.SMOKE if args.smoke else mod.FULL
-    mesh = make_host_mesh(args.model_parallel)
-    key = jax.random.PRNGKey(args.seed)
-
-    from repro.models import api
-    params = api.init_model(key, cfg)
-    tr, _ = SF.split_trainable(params, args.train_mode)
-    opt = adam_init(tr)
-    step_fn = SF.make_train_step(cfg, lr=args.lr, train_mode=args.train_mode)
-
-    pspec = SH.param_specs(cfg, params, mesh)
-    shard = lambda t: SH.to_named(mesh, t)
-    ckpt = CheckpointManager(args.ckpt_dir, keep=2)
-    start_step = 0
-    restored = ckpt.restore_latest({"params": params, "opt": opt})
-    if restored is not None:
-        (state, meta) = restored
-        params, opt = state["params"], state["opt"]
-        start_step = meta["step"]
-        print(f"[train] resumed from step {start_step}")
-
-    jit_step = jax.jit(step_fn)
-    batches = synthetic_token_batches(cfg.vocab, args.batch, args.seq,
-                                      args.steps, seed=args.seed,
-                                      n_codebooks=cfg.n_codebooks)
-    t0 = time.time()
-    with mesh:
-        for i, batch in enumerate(batches):
-            step = start_step + i
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            if cfg.family == "vlm":
-                batch["patches"] = jnp.zeros(
-                    (args.batch, cfg.n_patches, cfg.d_model),
-                    cfg.runtime_dtype())
-            params, opt, metrics = jit_step(params, opt, batch)
-            if (step + 1) % args.log_every == 0:
-                print(f"[train] step {step+1} loss "
-                      f"{float(metrics['loss']):.4f} "
-                      f"({(time.time()-t0)/(i+1):.2f}s/step)")
-            if (step + 1) % args.ckpt_every == 0:
-                ckpt.save(step + 1, {"params": params, "opt": opt},
-                          {"arch": args.arch})
-    final = float(metrics["loss"])
-    print(f"[train] done at step {start_step + args.steps}, loss {final:.4f}")
+    losses = run_backbone(
+        cfg, make_host_mesh(args.model_parallel), steps=args.steps,
+        batch=args.batch, seq=args.seq, lr=args.lr,
+        train_mode=args.train_mode, seed=args.seed,
+        log_every=args.log_every,
+        ckpt=CheckpointManager(args.ckpt_dir, keep=2),
+        ckpt_every=args.ckpt_every)
+    final = losses[-1]
+    print(f"[train] done after {len(losses)} steps, loss {final:.4f}")
     return final
 
 
@@ -129,6 +164,9 @@ def main():
     ap.add_argument("--eval-every", type=int, default=5)
     ap.add_argument("--windows", type=int, default=160)
     args = ap.parse_args()
+    from repro.kernels.runtime import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "backbone":
         train_backbone(args)
     else:

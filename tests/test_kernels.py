@@ -52,21 +52,6 @@ def test_mdlora_masked_blocks_are_inert():
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
 
 
-@pytest.mark.skipif(jax.default_backend() == "cpu",
-                    reason="needs a compiled pallas backend (TPU/GPU)")
-def test_mdlora_lowers_compiled():
-    """Smoke: the mdlora kernel compiles non-interpreted off-CPU."""
-    from repro.kernels.mdlora.ops import block_row_mask, mdlora_matmul
-
-    T, D, F, r = 128, 128, 128, 8
-    x = randn((T, D))
-    w0, a, b = randn((D, F), scale=0.05), randn((D, r)), randn((r, F))
-    mask = block_row_mask([D // 2, D // 2], [1.0, 0.0])
-    out = mdlora_matmul(x, w0, a, b, mask, impl="pallas", interpret=False,
-                        bt=64, bf=64, bd=64)
-    assert np.isfinite(np.asarray(out)).all()
-
-
 @pytest.mark.parametrize("B,D,F,r,A", [(8, 64, 128, 4, 3), (16, 128, 64, 8, 16),
                                        (4, 256, 128, 16, 2)])
 def test_mdlora_multi_gathered_matches_per_row_loop(B, D, F, r, A):
@@ -125,21 +110,6 @@ def test_mdlora_multi_matches_single_when_uniform():
     y2 = mdlora_matmul_multi(x, w0, a, b, jnp.zeros(B, jnp.int32),
                              impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
-
-
-@pytest.mark.skipif(jax.default_backend() == "cpu",
-                    reason="needs a compiled pallas backend (TPU/GPU)")
-def test_mdlora_multi_lowers_compiled():
-    from repro.kernels.mdlora.ops import mdlora_matmul_multi
-
-    B, D, F, r, A = 16, 128, 128, 8, 4
-    x = randn((B, D))
-    w0, a = randn((D, F), scale=0.05), randn((A, D, r), scale=0.1)
-    b = randn((A, r, F), scale=0.1)
-    idx = jnp.asarray(KEY.integers(0, A, B), jnp.int32)
-    out = mdlora_matmul_multi(x, w0, a, b, idx, impl="pallas",
-                              interpret=False)
-    assert np.isfinite(np.asarray(out)).all()
 
 
 def test_mdlora_autotune_blocks_and_roofline_plan():
@@ -270,7 +240,8 @@ def test_cohort_agg_quant_empty_cohort():
 
 
 def test_cohort_agg_explicit_bd_snaps_to_divisor():
-    """bd larger than (or not dividing) D must snap, not silently misindex."""
+    """bd larger than (or not dividing) D must snap to a legal block, not
+    silently misindex."""
     from repro.kernels.cohort_agg.ops import cohort_agg_divergence
 
     N, D, r = 6, 96, 4
@@ -278,7 +249,7 @@ def test_cohort_agg_explicit_bd_snaps_to_divisor():
     W = jnp.asarray(KEY.random((N, D)), jnp.float32)
     C = jnp.asarray(KEY.random((N, D)) < 0.5, jnp.float32)
     ref = cohort_agg_divergence(deltas, W, C, impl="xla")
-    for bd in (256, 64, 7):  # snap to 96, 48, 6
+    for bd in (256, 64, 7):  # no 128k divisor of 96: all snap to 96
         got = cohort_agg_divergence(deltas, W, C, impl="pallas",
                                     interpret=True, bd=bd)
         for a, b in zip(ref, got):
@@ -287,14 +258,19 @@ def test_cohort_agg_explicit_bd_snaps_to_divisor():
 
 
 def test_cohort_agg_autotune_candidates():
+    from repro.kernels import runtime
     from repro.kernels.cohort_agg import autotune
 
-    assert autotune.largest_divisor(96, 64) == 48
-    assert autotune.largest_divisor(100, 256) == 100
-    assert autotune.largest_divisor(97, 64) == 1  # prime > cap
-    for D in (96, 100, 256, 4096):
+    assert runtime.legal_tile(1024, 256) == 256
+    assert runtime.legal_tile(384, 256) == 128  # largest 128k divisor <= cap
+    assert runtime.legal_tile(96, 64) == 96  # no 128k divisor: whole dim
+    assert runtime.legal_tile(1600, 512) == 1600  # hymba d_model
+    assert runtime.legal_tile(1024, 64) == 128  # nothing fits under the cap
+    for D in (96, 100, 112, 256, 1600, 4096):
         cands = autotune.candidate_bds(D, r=4)
-        assert cands and all(D % bd == 0 for bd in cands)
+        assert cands and all(D % bd == 0 and (bd % 128 == 0 or bd == D)
+                             for bd in cands)
+    assert autotune.candidate_bds(1600, r=8) == [1600]
     bd = autotune.select_block_size((8, 256, 4), impl="pallas",
                                     interpret=True, quant=False)
     assert 256 % bd == 0
@@ -315,17 +291,41 @@ def test_cohort_agg_default_interpret_tracks_backend():
     assert resolve_interpret(False) is False
 
 
-@pytest.mark.skipif(jax.default_backend() == "cpu",
-                    reason="needs a compiled pallas backend (TPU/GPU)")
-def test_cohort_agg_quant_lowers_compiled():
-    """Smoke: the quant kernel compiles non-interpreted off-CPU."""
-    from repro.kernels.cohort_agg.ops import cohort_agg_divergence_quant
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache is the
+    fixed <repo>/.jax_cache."""
+    import pathlib
 
-    q, scales, W, C, staleness = _quant_inputs(8, 256, 4)
-    out = cohort_agg_divergence_quant(q, scales, W, C, staleness,
-                                      exponent=0.5, impl="pallas",
-                                      interpret=None)
-    assert np.isfinite(np.asarray(out[0])).all()
+    from repro.kernels import runtime
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert runtime.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert runtime.enable_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("bd", [64, 96, 200])
+def test_kernels_reject_illegal_tiles(bd):
+    """A block that is neither a multiple of 128 dividing the dim nor the
+    whole dim is refused at the kernel entry, as the TPU compiler would."""
+    from repro.kernels.cohort_agg.kernel import cohort_agg_divergence_pallas
+    from repro.kernels.mdlora.kernel import mdlora_matmul_pallas
+
+    N, D, r = 4, 256, 4
+    with pytest.raises(ValueError, match="legal TPU block"):
+        cohort_agg_divergence_pallas(randn((N, D, r)), randn((N, D)),
+                                     randn((N, D)), bd=bd, interpret=True)
+    with pytest.raises(ValueError, match="legal TPU block"):
+        mdlora_matmul_pallas(randn((16, D)), randn((D, 128)), randn((D, r)),
+                             randn((r, 128)), jnp.ones((D,)), 2.0, bt=16,
+                             bf=128, bd=bd, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -383,22 +383,6 @@ def test_flash_attention_bf16():
                                np.asarray(ref, np.float32), atol=3e-2)
 
 
-@pytest.mark.skipif(jax.default_backend() == "cpu",
-                    reason="needs a compiled pallas backend (TPU/GPU)")
-def test_flash_attention_lowers_compiled():
-    """Smoke: flash attention compiles non-interpreted off-CPU."""
-    from repro.kernels.flash_attention.ops import flash_attention
-
-    B, S, K, G, hd = 2, 128, 2, 2, 32
-    q = randn((B, S, K, G, hd))
-    k = randn((B, S, K, hd))
-    v = randn((B, S, K, hd))
-    pos = jnp.arange(S, dtype=jnp.int32)
-    out = flash_attention(q, k, v, pos, pos, None, None, impl="pallas",
-                          interpret=False, bq=32, bt=32)
-    assert np.isfinite(np.asarray(out)).all()
-
-
 # ---------------------------------------------------------------------------
 # ssd
 # ---------------------------------------------------------------------------
@@ -445,19 +429,3 @@ def test_ssd_kernel_matches_sequential_recurrence():
     np.testing.assert_allclose(np.asarray(fs), np.asarray(state), atol=1e-4)
 
 
-@pytest.mark.skipif(jax.default_backend() == "cpu",
-                    reason="needs a compiled pallas backend (TPU/GPU)")
-def test_ssd_lowers_compiled():
-    """Smoke: the ssd scan kernel compiles non-interpreted off-CPU."""
-    from repro.kernels.ssd.ops import ssd
-
-    b, s, h, p, n = 2, 128, 8, 16, 8
-    x = randn((b, s, h, p))
-    dt = jax.nn.softplus(randn((b, s, h)))
-    A_log = randn((h,))
-    Bm = randn((b, s, n))
-    Cm = randn((b, s, n))
-    y, fs = ssd(x, dt, A_log, Bm, Cm, chunk=32, impl="pallas",
-                interpret=False, bh=8)
-    assert np.isfinite(np.asarray(y)).all()
-    assert np.isfinite(np.asarray(fs)).all()

@@ -56,6 +56,27 @@ def test_act_hint_noop_without_mesh():
     assert act_hint(x, "batch", "model") is x
 
 
+def test_host_mesh_axes_are_auto_and_act_hint_lowers():
+    """Sharding constraints are only legal on Auto mesh axes; the host mesh
+    must be built that way or every act_hint under it fails to trace."""
+    import jax.numpy as jnp
+    from jax.sharding import AxisType
+
+    from repro.dist.sharding import act_hint, set_activation_mesh
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh()
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    set_activation_mesh(mesh)
+    try:
+        with mesh:
+            text = jax.jit(lambda x: act_hint(x * 2.0, "batch", None)).lower(
+                jnp.ones((len(jax.devices()) * 2, 4))).as_text()
+    finally:
+        set_activation_mesh(None)
+    assert "sharding" in text
+
+
 def test_strategy_selection():
     from repro.dist.sharding import pick_strategy
 
@@ -84,7 +105,8 @@ from repro.dist import sharding as SH
 from repro.launch import step_fns as SF
 from repro.launch import roofline as RL
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model_parallel=2)  # (4, 2) over the 8 host devices
 mod = base.get_arch("granite-3-8b")
 cfg = dataclasses.replace(mod.SMOKE, n_layers=2, scan_layers=False)
 shape = base.ShapeConfig("t", 64, 8, "train")
@@ -103,8 +125,6 @@ with mesh:
     compiled = jax.jit(fn, in_shardings=(sh(pspec), sh(ospec), sh(bspec))
                        ).lower(params, opt, batch).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jaxlib<0.4.38: one entry per device
-        ca = ca[0] if ca else {}
     coll = RL.parse_collectives(compiled.as_text())
 print(json.dumps({"flops": ca.get("flops", 0),
                   "colls": sum(coll.counts.values())}))
