@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import dist
+from repro import dist, obs
 from repro.core import aggregation as AG
 from repro.core import mdlora
 from repro.core.engine import (AllocPlan, FedConfig, _rank_gates, allocate,
@@ -267,6 +267,7 @@ class _ServerFlushMixin:
         """Simulated uplink cost per shipped parameter (int8 = 1 byte)."""
         return 1.0 if self.fed.uplink_codec == "int8" else 4.0
 
+    @obs.traced("fed.aggregate")
     def _flush_arrays(self, deltas: Any, S: np.ndarray,
                       client_ids: np.ndarray, losses: np.ndarray | None,
                       staleness: np.ndarray,
@@ -297,61 +298,63 @@ class _ServerFlushMixin:
             S = S * fresh[:, None]
 
         if deltas is not None:
-            trained = jnp.asarray(S, jnp.float32)
-            mmask = jnp.asarray(mmask_rows, jnp.float32)
-            a = self.strategy.staleness_exponent
-            scale = (None if a == 0.0
-                     else AG.staleness_discounts(staleness, a))
-            # quantized ingest applies the discount *inside* the fused
-            # reduction, so keep it out of the numerator (defer_scale)
-            wkw = dict(client_scale=scale, defer_scale=quant)
-            if self.strategy.agg == "cohort":
-                W = AG.cohort_weights(layout, trained, mmask, **wkw)
-            else:  # fedavg: every (fresh) buffered client into every
-                # non-empty group — max_staleness drops apply here too
-                ones = jnp.asarray(
-                    np.tile(layout.sizes[None, :] > 0, (K, 1))
-                    & fresh[:, None], jnp.float32)
-                W = AG.cohort_weights(layout, ones, jnp.ones_like(mmask),
-                                      **wkw)
+            with obs.span("fed.ingest"):
+                trained = jnp.asarray(S, jnp.float32)
+                mmask = jnp.asarray(mmask_rows, jnp.float32)
+                a = self.strategy.staleness_exponent
+                scale = (None if a == 0.0
+                         else AG.staleness_discounts(staleness, a))
+                # quantized ingest applies the discount *inside* the fused
+                # reduction, so keep it out of the numerator (defer_scale)
+                wkw = dict(client_scale=scale, defer_scale=quant)
+                if self.strategy.agg == "cohort":
+                    W = AG.cohort_weights(layout, trained, mmask, **wkw)
+                else:  # fedavg: every (fresh) buffered client into every
+                    # non-empty group — max_staleness drops apply here too
+                    ones = jnp.asarray(
+                        np.tile(layout.sizes[None, :] > 0, (K, 1))
+                        & fresh[:, None], jnp.float32)
+                    W = AG.cohort_weights(layout, ones, jnp.ones_like(mmask),
+                                          **wkw)
 
-            # divergence cohort: possession AND trained (paper Eq. 5 on the
-            # buffered subset)
-            acc = layout.accessible(mmask_rows)
-            C = jnp.asarray(acc & (S > 0), jnp.float32)
+                # divergence cohort: possession AND trained (paper Eq. 5 on the
+                # buffered subset)
+                acc = layout.accessible(mmask_rows)
+                C = jnp.asarray(acc & (S > 0), jnp.float32)
 
-            self.aggbuf.reset()
-            if quant:
-                self.aggbuf.push_quantized(
-                    deltas.q, deltas.scales, W, C,
-                    jnp.asarray(staleness, jnp.float32), a)
-            else:
-                self.aggbuf.push(deltas, W, C)
-            agg_tree, d, cnt = self.aggbuf.finalize()
+                self.aggbuf.reset()
+                if quant:
+                    self.aggbuf.push_quantized(
+                        deltas.q, deltas.scales, W, C,
+                        jnp.asarray(staleness, jnp.float32), a)
+                else:
+                    self.aggbuf.push(deltas, W, C)
+                agg_tree, d, cnt = self.aggbuf.finalize()
 
-            state.trainable = jax.tree.map(
-                lambda t, g: (t.astype(jnp.float32)
-                              + fed.server_lr * g).astype(t.dtype),
-                state.trainable, agg_tree)
+                state.trainable = jax.tree.map(
+                    lambda t, g: (t.astype(jnp.float32)
+                                  + fed.server_lr * g).astype(t.dtype),
+                    state.trainable, agg_tree)
 
-            d_np = np.asarray(d)
-            touched = np.asarray(cnt) > 0
-            state.dbar[touched] = (fed.gamma * d_np
-                                   + (1.0 - fed.gamma) * state.dbar)[touched]
-            if quant:  # magnitude EMA diagnostic over the K-client buffer
-                # (dequantizes [K, ...] for stats only — the hot reduction
-                # above never materialized it)
-                norm_src = dist.dequantize_int8_stacked(deltas.q,
-                                                        deltas.scales)
-            else:
-                norm_src = deltas
-            per_client_norms = np.asarray(jax.vmap(
-                lambda t: mdlora.group_norms(layout, t))(norm_src))
-            denom = np.maximum(S.sum(0), 1)
-            mag = (per_client_norms * S).sum(0) / denom
-            sel = S.any(0)
-            state.mag_ema[sel] = (0.5 * state.mag_ema + 0.5 * mag)[sel]
-            loss = float(np.mean(losses))
+            with obs.span("fed.stats"):
+                d_np = np.asarray(d)
+                touched = np.asarray(cnt) > 0
+                state.dbar[touched] = (fed.gamma * d_np
+                                       + (1.0 - fed.gamma) * state.dbar)[touched]
+                if quant:  # magnitude EMA diagnostic over the K-client buffer
+                    # (dequantizes [K, ...] for stats only — the hot reduction
+                    # above never materialized it)
+                    norm_src = dist.dequantize_int8_stacked(deltas.q,
+                                                            deltas.scales)
+                else:
+                    norm_src = deltas
+                per_client_norms = np.asarray(jax.vmap(
+                    lambda t: mdlora.group_norms(layout, t))(norm_src))
+                denom = np.maximum(S.sum(0), 1)
+                mag = (per_client_norms * S).sum(0) / denom
+                sel = S.any(0)
+                state.mag_ema[sel] = (0.5 * state.mag_ema + 0.5 * mag)[sel]
+                loss = float(np.mean(losses))
         else:  # system-only simulation: no gradient work this flush
             d_np = np.zeros(layout.G)
             loss = float("nan")
@@ -705,12 +708,16 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
     def _dispatch_vec(self, idx: np.ndarray, now: float, dataset) -> None:
         """Pull the current model to clients ``idx`` and schedule their
         completions — array-resident, O(batch) given the cached AllocPlan."""
+        idx = np.asarray(idx, np.int64)
+        if len(idx) == 0:
+            return
+        with obs.span("fed.dispatch", B=len(idx)):
+            self._dispatch_rows(idx, now, dataset)
+
+    def _dispatch_rows(self, idx: np.ndarray, now: float, dataset) -> None:
         task, fed, fleet = self.task, self.fed, self.fleet
         layout, state = task.layout, self.state
-        idx = np.asarray(idx, np.int64)
         B = len(idx)
-        if B == 0:
-            return
         sched = fed.modality_schedule
         live_mm = (sched.masks_at(now, idx) if sched is not None
                    else fleet.modality_mask[idx])
@@ -739,9 +746,10 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                 state.trainable)
             gates = jnp.asarray(S, jnp.float32)
             mmasks = jnp.asarray(live_mm, jnp.float32)
-            deltas, losses = self.local_update(
-                start, batches, mmasks, gates, self._rank_gate_rows(B),
-                fed.lr)
+            with obs.span("fed.local_update"):
+                deltas, losses = self.local_update(
+                    start, batches, mmasks, gates, self._rank_gate_rows(B),
+                    fed.lr)
             if fault is not None:  # corrupt pre-quantization (heap parity)
                 deltas = self.fx.corrupt(deltas, fault[2], idx, fault[3])
             if self.strategy.selective:  # FedMFS: shrink upload, not compute
@@ -830,30 +838,33 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
         flushed clients only, each starting from the ring snapshot of the
         version it pulled (pulls older than the ring clamp to the oldest
         retained snapshot; ``ring_clamped`` counts those)."""
-        fed, fleet, state = self.fed, self.fleet, self.state
-        R = max(1, fed.snapshot_ring)
-        vmin = max(0, state.round - R + 1)
-        v_eff = np.maximum(versions, vmin)
-        self.ring_clamped += int(np.sum(v_eff != versions))
-        start = jax.tree.map(lambda x: x[jnp.asarray(v_eff % R)], self._ring)
+        fed, state = self.fed, self.state
+        with obs.span("fed.assemble"):
+            R = max(1, fed.snapshot_ring)
+            vmin = max(0, state.round - R + 1)
+            v_eff = np.maximum(versions, vmin)
+            self.ring_clamped += int(np.sum(v_eff != versions))
+            start = jax.tree.map(lambda x: x[jnp.asarray(v_eff % R)],
+                                 self._ring)
 
-        steps = fed.local_epochs * fed.steps_per_epoch
-        xs, ys = [], []
-        for c, t in zip(ids, tickets):  # counter-based draws: order-free
-            r = np.random.default_rng([fed.seed, int(c), int(t)])
-            src = int(c) % len(dataset.train_y)
-            bidx = r.integers(0, len(dataset.train_y[src]),
-                              size=(steps, fed.batch_size))
-            xs.append(dataset.train_x[src][bidx])
-            ys.append(dataset.train_y[src][bidx])
-        batches = {"x": jnp.asarray(np.stack(xs)),
-                   "y": jnp.asarray(np.stack(ys))}
-        gates = jnp.asarray(S, jnp.float32)
-        mmasks = jnp.asarray(mmask_rows, jnp.float32)
-        deltas, losses = self.local_update(
-            start, batches, mmasks, gates, self._rank_gate_rows(len(ids)),
-            fed.lr)
-        return deltas, np.asarray(losses)
+            steps = fed.local_epochs * fed.steps_per_epoch
+            xs, ys = [], []
+            for c, t in zip(ids, tickets):  # counter-based draws: order-free
+                r = np.random.default_rng([fed.seed, int(c), int(t)])
+                src = int(c) % len(dataset.train_y)
+                bidx = r.integers(0, len(dataset.train_y[src]),
+                                  size=(steps, fed.batch_size))
+                xs.append(dataset.train_x[src][bidx])
+                ys.append(dataset.train_y[src][bidx])
+            batches = {"x": jnp.asarray(np.stack(xs)),
+                       "y": jnp.asarray(np.stack(ys))}
+            gates = jnp.asarray(S, jnp.float32)
+            mmasks = jnp.asarray(mmask_rows, jnp.float32)
+        with obs.span("fed.local_update"):
+            deltas, losses = self.local_update(
+                start, batches, mmasks, gates, self._rank_gate_rows(len(ids)),
+                fed.lr)
+            return deltas, np.asarray(losses)
 
     def _flush_vec(self, dataset) -> dict:
         client = np.concatenate(self._buf_client)
@@ -889,7 +900,8 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
             if quantize:  # cohort-sampled gradients quantize at the edge
                 # of the simulated uplink (no EF: each (client, ticket)
                 # update is drawn exactly once at flush time)
-                qt, sc, _ = dist.quantize_int8_stacked(deltas)
+                with obs.span("fed.uplink"):
+                    qt, sc, _ = dist.quantize_int8_stacked(deltas)
                 deltas = AG.QuantizedStack(qt, sc)
         else:
             deltas, losses = None, None
@@ -926,13 +938,17 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                                           fs.upload_bytes[chunk])
             self._buf_append(chunk)
             if self._buf_count >= K:
-                rec = self._flush_vec(dataset)
+                obs.clock()
+                with obs.span("fed.flush", K=self._buf_count,
+                              round=self.state.round):
+                    rec = self._flush_vec(dataset)
                 self._log_and_eval(rec, dataset if self.grad_mode != "none"
                                    else None, log_every,
                                    f"vec:{self.strategy.name}")
 
     # -- the vectorized event loop --------------------------------------------
 
+    @obs.traced("fed.run")
     def run(self, dataset=None, total_updates: int | None = None,
             log_every: int = 0) -> dict:
         """Absorb ``total_updates`` completions (default rounds * N), with

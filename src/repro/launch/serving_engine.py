@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models import api
 
@@ -47,14 +48,14 @@ Array = jax.Array
 
 @functools.lru_cache(maxsize=None)
 def _decode_fn(cfg: ModelConfig, lora_impl: str):
-    def f(base, store, fmasks, caches, token, pos, aidx):
+    def serve_decode(base, store, fmasks, caches, token, pos, aidx):
         fmask = jnp.take(fmasks, aidx, axis=0)
         logits, caches = api.decode_step({"base": base, "lora": store}, cfg,
                                          caches, token, pos,
                                          adapter_idx=aidx, fusion_mask=fmask,
                                          lora_impl=lora_impl)
         return jnp.argmax(logits[:, 0], -1).astype(jnp.int32), caches
-    return jax.jit(f)
+    return jax.jit(serve_decode)
 
 
 @functools.lru_cache(maxsize=None)
@@ -62,7 +63,7 @@ def _admit_fn(cfg: ModelConfig):
     """Admission as one fused call: gather the adapter from the store,
     prefill into a fresh single-row cache, scatter that row into the shared
     cache at ``slot`` and return the first greedy token."""
-    def f(base, store, fmasks, fresh, big, tokens, aslot, slot):
+    def serve_admit(base, store, fmasks, fresh, big, tokens, aslot, slot):
         lora = jax.tree.map(lambda x: x[:, aslot], store)
         logits, small = api.prefill_with_cache(
             {"base": base, "lora": lora}, cfg, fresh, tokens,
@@ -71,7 +72,7 @@ def _admit_fn(cfg: ModelConfig):
             lambda b, o: b.at[:, slot].set(o[:, 0].astype(b.dtype)),
             big, small)
         return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), big
-    return jax.jit(f)  # jit's shape cache handles varying prompt lengths
+    return jax.jit(serve_admit)  # jit's shape cache: one per prompt length
 
 
 @functools.lru_cache(maxsize=None)
@@ -103,6 +104,38 @@ class Request:
     adapter: str  # registry name
     max_new_tokens: int = 16
     submit_t: float = 0.0
+
+
+@dataclasses.dataclass
+class RequestTimes:
+    """One request's stamps (``obs.now()``); None until it happens."""
+    rid: str
+    submit: float
+    admit: float  # admission started
+    finish: float | None = None
+
+    @property
+    def queue_wait(self) -> float:
+        return self.admit - self.submit
+
+
+@dataclasses.dataclass
+class StepTimes:
+    """One ``step()``: host seconds in it and in its phases. The ring of
+    them is the engine's flight record: ``bench/program.py`` reports the
+    slowest step of a traced window with the device's busy time in it."""
+    t0: float  # stamp at the start
+    total: float
+    admit: float  # in its admissions
+    decode: float  # in its decode call and token read (0: no decode)
+    queued: int  # queue depth at the start
+    admitted: int
+    active: int  # rows decoded
+
+    @property
+    def host(self) -> float:
+        """Outside the admissions and the decode: the host loop."""
+        return self.total - self.admit - self.decode
 
 
 # ---------------------------------------------------------------------------
@@ -227,22 +260,25 @@ class ServingEngine:
         self.cur = np.zeros((batch_slots, 1), np.int32)
         self.outputs: dict[str, list[int]] = {}
         self.latency: dict[str, float] = {}
-        self.step_times: list[float] = []
-        self._submit_times: dict[str, float] = {}
+        # rings "request" (RequestTimes, at admission) and "step" (StepTimes)
+        self.stats = obs.Rings()
+        self._times: list[RequestTimes | None] = [None] * batch_slots
         self._decode = _decode_fn(cfg, lora_impl)
         self._admit_step = _admit_fn(cfg)
         # immutable zeroed single-row cache reused by every admission
         self._fresh_row = api.init_caches(cfg, 1, max_len, per_row_pos=True)
 
     def submit(self, req: Request) -> None:
-        req.submit_t = time.perf_counter()
-        self._submit_times[req.rid] = req.submit_t
+        req.submit_t = obs.now()
         self.queue.append(req)
         self.outputs[req.rid] = []
 
     # -- admission ---------------------------------------------------------
 
     def _admit(self, slot: int, req: Request) -> None:
+        rec = RequestTimes(req.rid, req.submit_t, obs.now())
+        self.stats.record("request", rec)
+        self._times[slot] = rec
         aslot = self.registry.slot(req.adapter)
         tokens = jnp.asarray(req.prompt, jnp.int32)[None]
         # one fused dispatch: gather adapter, prefill the fresh row, scatter
@@ -267,8 +303,9 @@ class ServingEngine:
 
     def _retire(self, slot: int) -> None:
         rid = self.rids[slot]
-        self.latency[rid] = (time.perf_counter()
-                             - self._submit_times.get(rid, 0.0))
+        rec = self._times[slot]
+        rec.finish = obs.now()
+        self.latency[rid] = rec.finish - rec.submit
         self.active[slot] = False
         self.rids[slot] = None
 
@@ -276,29 +313,43 @@ class ServingEngine:
 
     def step(self) -> int:
         """Admit what fits, run one batched decode step; -> #active rows."""
-        for slot in range(self.B):
-            if not self.active[slot] and self.queue:
-                self._admit(slot, self.queue.pop(0))
-        if not self.active.any():
-            return 0
-        t0 = time.perf_counter()
-        tok, self.caches = self._decode(
-            self.params["base"], self.registry.store,
-            self.registry.fusion_masks, self.caches,
-            jnp.asarray(self.cur), jnp.asarray(self.pos),
-            jnp.asarray(self.adapter_idx))
-        nxt = np.asarray(tok)
-        self.step_times.append(time.perf_counter() - t0)
-        for slot in range(self.B):
-            if not self.active[slot]:
-                continue
-            self.pos[slot] += 1
-            self.cur[slot, 0] = nxt[slot]
-            self.outputs[self.rids[slot]].append(int(nxt[slot]))
-            self.remaining[slot] -= 1
-            if (self.remaining[slot] <= 0
-                    or self.pos[slot] >= self.max_len - 1):
-                self._retire(slot)
+        obs.clock()
+        queued = len(self.queue)
+        admitted = min(int((~self.active).sum()), queued)
+        admit_s = decode_s = 0.0
+        rows = 0
+        with obs.span("serve.step", admitted=admitted,
+                      active=int(self.active.sum())) as st:
+            for slot in range(self.B):
+                if not self.active[slot] and self.queue:
+                    req = self.queue.pop(0)
+                    with obs.span("serve.admit", rid=req.rid, slot=slot,
+                                  prompt_len=len(req.prompt)) as sp:
+                        self._admit(slot, req)
+                    admit_s += sp.t1 - sp.t0
+            if self.active.any():
+                rows = int(self.active.sum())
+                with obs.span("serve.decode", rows=rows) as sp:
+                    tok, self.caches = self._decode(
+                        self.params["base"], self.registry.store,
+                        self.registry.fusion_masks, self.caches,
+                        jnp.asarray(self.cur), jnp.asarray(self.pos),
+                        jnp.asarray(self.adapter_idx))
+                    nxt = np.asarray(tok)
+                decode_s = sp.t1 - sp.t0
+                for slot in range(self.B):
+                    if not self.active[slot]:
+                        continue
+                    self.pos[slot] += 1
+                    self.cur[slot, 0] = nxt[slot]
+                    self.outputs[self.rids[slot]].append(int(nxt[slot]))
+                    self.remaining[slot] -= 1
+                    if (self.remaining[slot] <= 0
+                            or self.pos[slot] >= self.max_len - 1):
+                        self._retire(slot)
+        rec = StepTimes(st.t0, st.t1 - st.t0, admit_s, decode_s, queued,
+                        admitted, rows)
+        self.stats.record("step", rec)
         return int(self.active.sum())
 
     def run(self) -> dict:
@@ -320,7 +371,8 @@ class ServingEngine:
             "latency_p50_s": lat[len(lat) // 2],
             "latency_p99_s": lat[min(len(lat) - 1,
                                      int(np.ceil(0.99 * len(lat))) - 1)],
-            "decode_step_times": list(self.step_times),
+            "decode_step_times": [s.decode for s in self.stats.ring("step")
+                                  if s.active],
         }
 
 
