@@ -2,10 +2,10 @@
 host mesh. Demonstrates the serve path end-to-end (continuous greedy decode
 over a batch of synthetic prompts) for any assigned architecture.
 
-Prefill goes through ``models/api.prefill_with_cache``: attention archs run
-one chunked forward over the whole prompt (P-fold fewer dispatches than the
-historical per-token loop); recurrent archs (ssm/hybrid) keep the exact
-token loop their state recurrence requires.
+Prefill goes through ``models/api.prefill_with_cache``: attention and
+hybrid archs run one forward over the whole prompt where it fits the
+attention ring (``api.prefill_one_pass``); longer prompts and the ssm
+family keep the exact token loop.
 
 ``--engine`` demos the continuous-batching multi-LoRA path instead: N
 personalized adapters, requests joining/leaving the decode batch mid-stream
@@ -112,8 +112,8 @@ def main():
     serve_step = jax.jit(SF.make_serve_step(cfg))
     caches = api.init_caches(cfg, B, max_len)
 
-    # chunked prefill (attention archs: one forward; ssm/hybrid: the cache
-    # path is the recurrence, so api falls back to the exact token loop)
+    # one forward where the prompt fits the attention ring, else the exact
+    # token loop (api.prefill_one_pass)
     t0 = time.time()
     logits, caches = api.prefill_with_cache(params, cfg, caches, prompts)
     tok = jnp.argmax(logits, axis=-1).astype(prompts.dtype)

@@ -113,6 +113,7 @@ class RequestTimes:
     submit: float
     admit: float  # admission started
     finish: float | None = None
+    one_pass: bool = False  # prefilled in one forward (api.prefill_one_pass)
 
     @property
     def queue_wait(self) -> float:
@@ -224,9 +225,9 @@ class ServingEngine:
     """Continuous-batching decode loop over ``batch_slots`` cache rows.
 
     Every step: (1) free slots are filled from the admission queue — the
-    prompt is prefilled into a fresh single-row cache (chunked forward for
-    attention archs, exact token loop for recurrent ones) and the row is
-    scattered into the shared cache; (2) one jitted batched decode step
+    prompt is prefilled into a fresh single-row cache (one forward where
+    ``api.prefill_one_pass`` allows, else the exact token loop) and the row
+    is scattered into the shared cache; (2) one jitted batched decode step
     advances all active rows, each applying its own adapter via the
     gathered mdlora kernel. Finished rows are recycled immediately.
 
@@ -275,8 +276,9 @@ class ServingEngine:
 
     # -- admission ---------------------------------------------------------
 
-    def _admit(self, slot: int, req: Request) -> None:
-        rec = RequestTimes(req.rid, req.submit_t, obs.now())
+    def _admit(self, slot: int, req: Request, one_pass: bool) -> None:
+        rec = RequestTimes(req.rid, req.submit_t, obs.now(),
+                           one_pass=one_pass)
         self.stats.record("request", rec)
         self._times[slot] = rec
         aslot = self.registry.slot(req.adapter)
@@ -323,9 +325,12 @@ class ServingEngine:
             for slot in range(self.B):
                 if not self.active[slot] and self.queue:
                     req = self.queue.pop(0)
+                    one_pass = api.prefill_one_pass(
+                        self.cfg, self._fresh_row, len(req.prompt))
                     with obs.span("serve.admit", rid=req.rid, slot=slot,
-                                  prompt_len=len(req.prompt)) as sp:
-                        self._admit(slot, req)
+                                  prompt_len=len(req.prompt),
+                                  one_pass=one_pass) as sp:
+                        self._admit(slot, req, one_pass)
                     admit_s += sp.t1 - sp.t0
             if self.active.any():
                 rows = int(self.active.sum())

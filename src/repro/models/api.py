@@ -162,6 +162,20 @@ def fusion_block_dims(cfg: ModelConfig) -> tuple[int, ...]:
     raise ValueError(f"{cfg.family} has no fusion projection")
 
 
+def prefill_one_pass(cfg: ModelConfig, caches: Any, S: int) -> bool:
+    """Whether ``prefill_with_cache`` runs an S-token prompt as one forward
+    (else as S decode steps): an attention or hybrid family, and every
+    attention ring of ``caches`` holds the whole prompt (a longer prompt
+    would overwrite ring slots mid-forward) in whole query chunks."""
+    if cfg.family == "hybrid":
+        rings = [caches["attn"]["k"].shape[2]]
+    elif cfg.family in _TF_FAMILIES:
+        rings = [c["k"].shape[2] for c in caches.get("__per_sub__", [caches])]
+    else:
+        return False
+    return S <= min(rings) and (S <= cfg.q_chunk or S % cfg.q_chunk == 0)
+
+
 def prefill_with_cache(params: dict, cfg: ModelConfig, caches: Any,
                        tokens: Array, patches: Array | None = None,
                        fusion_mask: Array | None = None
@@ -169,40 +183,31 @@ def prefill_with_cache(params: dict, cfg: ModelConfig, caches: Any,
     """Prefill ``tokens`` [B, S] into ``caches``; -> (last-position logits
     [B, 1, V], updated caches).
 
-    Attention families run one chunked forward over the whole prompt (the
-    q_chunk-tiled attention bounds peak memory) when every cache ring can
-    hold it; prompts longer than a sliding-window ring would overwrite
-    slots mid-forward, so those fall back to the exact per-token loop.
-    Recurrent families (ssm, hybrid) must advance their state
-    token-by-token — the cache path *is* the recurrence there.
+    Where ``prefill_one_pass`` allows, one forward over the whole prompt:
+    attention families run the q_chunk-tiled attention forward; the hybrid
+    family runs it beside the chunked SSD scan from the cached state. Else
+    the exact loop of S decode steps: prompts longer than an attention
+    ring, and the ssm family, whose forward threads no caches.
     Assumes fresh caches (prefill starts at position 0).
     """
-    B, S = tokens.shape[:2]
-    if cfg.family in _TF_FAMILIES:
-        if isinstance(caches, dict) and "__per_sub__" in caches:
-            min_ring = min(c["k"].shape[2] for c in caches["__per_sub__"])
-        else:
-            min_ring = caches["k"].shape[2]
-        if S <= min_ring:
-            positions = jnp.arange(S, dtype=jnp.int32)
-            h, caches, _ = TF.lm_forward(params, cfg, tokens, patches=patches,
-                                         positions=positions, caches=caches,
-                                         skip_unembed=True,
-                                         fusion_mask=fusion_mask)
-            return TF.unembed(params, cfg, h[:, -1:]), caches
-        logits = None
-        for t in range(S):
-            logits, caches = decode_step(params, cfg, caches,
-                                         tokens[:, t:t + 1], jnp.int32(t),
-                                         fusion_mask=fusion_mask)
-        return logits, caches
-    if cfg.family not in ("ssm", "hybrid"):
+    S = tokens.shape[1]
+    if cfg.family not in _TF_FAMILIES + ("ssm", "hybrid"):
         raise ValueError(cfg.family)
+    if prefill_one_pass(cfg, caches, S):
+        if cfg.family == "hybrid":
+            return HY.hybrid_prefill(params, cfg, caches, tokens,
+                                     fusion_mask=fusion_mask)
+        positions = jnp.arange(S, dtype=jnp.int32)
+        h, caches, _ = TF.lm_forward(params, cfg, tokens, patches=patches,
+                                     positions=positions, caches=caches,
+                                     skip_unembed=True,
+                                     fusion_mask=fusion_mask)
+        return TF.unembed(params, cfg, h[:, -1:]), caches
     logits = None
     for t in range(S):
         logits, caches = decode_step(
             params, cfg, caches, tokens[:, t:t + 1], jnp.int32(t),
-            fusion_mask=fusion_mask if cfg.family == "hybrid" else None)
+            fusion_mask=None if cfg.family == "ssm" else fusion_mask)
     return logits, caches
 
 

@@ -205,18 +205,11 @@ def init_hybrid_caches(cfg: ModelConfig, batch: int, max_len: int,
     }
 
 
-def hybrid_decode_step(params: dict, cfg: ModelConfig, caches: dict,
-                       token: Array, pos: Array,
-                       adapter_idx: Array | None = None,
-                       fusion_mask: Array | None = None,
-                       lora_impl: str = "xla"):
-    x = jnp.take(params["base"]["embed"], token, axis=0).astype(cfg.runtime_dtype())
-    pos = jnp.asarray(pos, jnp.int32)
-    positions = pos[None] if pos.ndim == 0 else pos[:, None]
-    ctx = None
-    if adapter_idx is not None or fusion_mask is not None:
-        ctx = {"adapter_idx": adapter_idx, "fusion_mask": fusion_mask,
-               "lora_impl": lora_impl}
+def _cached_layers(params: dict, cfg: ModelConfig, caches: dict,
+                   tokens: Array, positions: Array, ctx: dict | None):
+    """Every layer over ``tokens`` [B, S] at ``positions``, reading and
+    writing ``caches``; -> (hidden before the final norm, new caches)."""
+    x = jnp.take(params["base"]["embed"], tokens, axis=0).astype(cfg.runtime_dtype())
     lora_layers = params.get("lora", {}).get("layers")
 
     def body(x, step):
@@ -226,15 +219,41 @@ def hybrid_decode_step(params: dict, cfg: ModelConfig, caches: dict,
         return x, nc
 
     if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(
+        return jax.lax.scan(
             body, x, (params["base"]["layers"], lora_layers, caches))
-    else:
-        ncs = []
-        for t in range(cfg.n_layers):
-            x, nc = body(x, jax.tree.map(
-                lambda a: a[t],
-                (params["base"]["layers"], lora_layers, caches)))
-            ncs.append(nc)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
+    ncs = []
+    for t in range(cfg.n_layers):
+        x, nc = body(x, jax.tree.map(
+            lambda a: a[t], (params["base"]["layers"], lora_layers, caches)))
+        ncs.append(nc)
+    return x, jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
+
+
+def hybrid_decode_step(params: dict, cfg: ModelConfig, caches: dict,
+                       token: Array, pos: Array,
+                       adapter_idx: Array | None = None,
+                       fusion_mask: Array | None = None,
+                       lora_impl: str = "xla"):
+    pos = jnp.asarray(pos, jnp.int32)
+    positions = pos[None] if pos.ndim == 0 else pos[:, None]
+    ctx = None
+    if adapter_idx is not None or fusion_mask is not None:
+        ctx = {"adapter_idx": adapter_idx, "fusion_mask": fusion_mask,
+               "lora_impl": lora_impl}
+    x, new_caches = _cached_layers(params, cfg, caches, token, positions, ctx)
     x = L.rmsnorm(params["base"]["final_norm"], x)
+    return unembed(params, cfg, x), new_caches
+
+
+def hybrid_prefill(params: dict, cfg: ModelConfig, caches: dict,
+                   tokens: Array, fusion_mask: Array | None = None):
+    """One forward over the whole prompt ``tokens`` [B, S] into fresh
+    ``caches`` (from position 0): the attention heads write all S positions
+    into the ring (so S must not exceed it), the SSD heads scan the prompt
+    in chunks from the cached state. -> (last-position logits [B, 1, V],
+    caches), as S ``hybrid_decode_step`` calls would give."""
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    ctx = None if fusion_mask is None else {"fusion_mask": fusion_mask}
+    x, new_caches = _cached_layers(params, cfg, caches, tokens, positions, ctx)
+    x = L.rmsnorm(params["base"]["final_norm"], x[:, -1:])
     return unembed(params, cfg, x), new_caches

@@ -98,6 +98,25 @@ def ssd_chunked(x: Array, dt: Array, A_log: Array, Bm: Array, Cm: Array,
     return y.astype(x.dtype), final_state
 
 
+def ssd_from_state(x: Array, dt: Array, A_log: Array, Bm: Array, Cm: Array,
+                   chunk: int, state: Array) -> tuple[Array, Array]:
+    """Chunked SSD scan of any length ``s`` from ``state`` [b, h, p, n]
+    (XLA path: the Pallas kernel takes no initial state). The whole
+    ``chunk``-sized chunks go first, then the ``s % chunk`` remainder as one
+    chunk from their final state; shapes as ``ssd_chunked``."""
+    s = x.shape[1]
+    whole = s - s % chunk
+    parts = [(0, whole, chunk)] if whole else []
+    if s > whole:
+        parts.append((whole, s, s - whole))
+    ys = []
+    for lo, hi, c in parts:
+        y, state = ssd_chunked(x[:, lo:hi], dt[:, lo:hi], A_log, Bm[:, lo:hi],
+                               Cm[:, lo:hi], c, initial_state=state)
+        ys.append(y)
+    return jnp.concatenate(ys, axis=1), state
+
+
 def ssd_decode_step(state: Array, x: Array, dt: Array, A_log: Array,
                     Bm: Array, Cm: Array) -> tuple[Array, Array]:
     """O(1) recurrent update. state: [b,h,p,n]; x: [b,h,p]; dt: [b,h];
@@ -171,7 +190,9 @@ def mamba_mixer(p: dict, cfg: ModelConfig, x: Array,
                 return_fused_input: bool = False, lp: dict | None = None):
     """x: [B, S, d] -> (y [B, S, d], new_cache).
 
-    ssm_cache = {"conv": [B, k-1, conv_dim], "state": [B, h, p, n]} for decode.
+    ssm_cache = {"conv": [B, k-1, conv_dim], "state": [B, h, p, n]}: the
+    S tokens continue from it (S == 1: the recurrent decode update; S > 1:
+    the chunked scan from the cached state, as in a one-pass prefill).
     ``return_fused_input`` exposes the pre-out_proj hidden (RELIEF fusion hook).
     """
     dm = mixer_dims(cfg)
@@ -193,11 +214,14 @@ def mamba_mixer(p: dict, cfg: ModelConfig, x: Array,
         y, final_state = ssd_chunked(xh, dt, p["A_log"], Bm, Cm,
                                      min(cfg.ssd_chunk, S), impl=cfg.attn_impl
                                      if cfg.attn_impl == "pallas" else "xla")
-    else:
+    elif S == 1:
         yh, final_state = ssd_decode_step(ssm_cache["state"], xh[:, 0],
                                           dt[:, 0], p["A_log"], Bm[:, 0],
                                           Cm[:, 0])
         y = yh[:, None]
+    else:
+        y, final_state = ssd_from_state(xh, dt, p["A_log"], Bm, Cm,
+                                        cfg.ssd_chunk, ssm_cache["state"])
     y = y + xh * p["D"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B_, S, dm["d_inner"])
     y = L.rmsnorm(p["norm"], y) * jax.nn.silu(z)

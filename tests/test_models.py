@@ -148,3 +148,98 @@ def test_vocab_padding_transparent():
     logits, _ = api.forward(params, cfg, {"tokens": jnp.zeros((1, 8),
                                                               jnp.int32)})
     assert logits.shape[-1] == cfg.vocab  # sliced back to the true vocab
+
+
+# ---------------------------------------------------------------------------
+# hybrid prefill: one pass over the prompt == the exact token loop
+# ---------------------------------------------------------------------------
+
+# SMOKE with 4-token SSD chunks, so that a prompt inside the 16-slot ring
+# holds whole chunks and a remainder
+_HY = dataclasses.replace(base.get_arch("hymba-1.5b").SMOKE, ssd_chunk=4)
+_HY_MAX_LEN = 24  # ring = min(sliding_window 16, 24)
+_decode = jax.jit(api.decode_step, static_argnums=1)
+_prefill = jax.jit(api.prefill_with_cache, static_argnums=1)
+
+
+@pytest.fixture(scope="module")
+def hybrid_client():
+    """SMOKE hymba (float32) with one client's adapter: LoRA b moved away
+    from zero, so a wrong adapter or mask changes the logits."""
+    params = api.init_model(KEY, _HY)
+    params["lora"] = jax.tree.map(
+        lambda x: x + 0.05 * jax.random.normal(jax.random.PRNGKey(3),
+                                               x.shape, x.dtype),
+        params["lora"])
+    return params
+
+
+@pytest.mark.parametrize("blocks", [(1, 1), (1, 0), (0, 1)],
+                         ids=["both", "attn_only", "ssd_only"])
+@pytest.mark.parametrize("S", [1, 3, 8, 11, 16, 21],
+                         ids=["1", "3", "chunks", "remainder", "ring",
+                              "past_ring"])
+def test_hybrid_one_pass_prefill_matches_token_loop(hybrid_client, S, blocks):
+    from repro.kernels.mdlora import block_row_mask
+
+    cfg, params = _HY, hybrid_client
+    fresh = api.init_caches(cfg, 1, _HY_MAX_LEN, per_row_pos=True)
+    ring = fresh["attn"]["k"].shape[2]
+    assert ring == 16 and api.prefill_one_pass(cfg, fresh, S) == (S <= ring)
+    fmask = block_row_mask(api.fusion_block_dims(cfg),
+                           jnp.asarray(blocks, jnp.float32))[None]
+    tokens = jax.random.randint(jax.random.PRNGKey(S), (1, S), 0, cfg.vocab)
+    caches = fresh
+    for t in range(S):
+        want, caches = _decode(params, cfg, caches, tokens[:, t:t + 1],
+                               jnp.int32(t), fusion_mask=fmask)
+    got, got_caches = _prefill(params, cfg, fresh, tokens, fusion_mask=fmask)
+    assert got.shape == want.shape == (1, 1, cfg.vocab)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert jax.tree.structure(got_caches) == jax.tree.structure(caches)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_caches),
+                            jax.tree.leaves(caches)):
+        assert a.dtype == b.dtype, path
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_prefill_one_pass_needs_ring_and_whole_query_chunks():
+    """One forward only where the ring holds the prompt and the attention
+    tiles it in whole q_chunk blocks; the ssm family always loops."""
+    cfg = dataclasses.replace(_HY, q_chunk=8)
+    fresh = api.init_caches(cfg, 1, _HY_MAX_LEN, per_row_pos=True)
+    assert [api.prefill_one_pass(cfg, fresh, S)
+            for S in (1, 8, 12, 16, 17)] == [True, True, False, True, False]
+    ssm = base.get_arch("mamba2-1.3b").SMOKE
+    assert not api.prefill_one_pass(ssm, api.init_caches(ssm, 1, 8), 4)
+
+
+@pytest.mark.parametrize("S", [2, 4, 7, 12],
+                         ids=["2", "chunk", "remainder", "chunks"])
+def test_mamba_mixer_multi_token_from_cache(S):
+    """S tokens through the mixer from a nonzero cache == S single-token
+    calls, in outputs and in the cache it returns (4-token SSD chunks)."""
+    from repro.models import ssm as SM
+
+    cfg = _HY
+    dm = SM.mixer_dims(cfg)
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(11), 4)
+    p = SM.init_mamba_mixer(k1, cfg)
+    p["dt_bias"] = 0.5 * jax.random.normal(k4, p["dt_bias"].shape)
+    x = jax.random.normal(k2, (2, S, cfg.d_model))
+    kc, ks = jax.random.split(k3)
+    cache = {"conv": jax.random.normal(kc, (2, cfg.conv_kernel - 1,
+                                            dm["conv_dim"])),
+             "state": jax.random.normal(ks, (2, dm["n_heads"], dm["p"],
+                                             dm["n"]))}
+    got, got_cache = SM.mamba_mixer(p, cfg, x, ssm_cache=cache)
+    want, c = [], cache
+    for t in range(S):
+        y, c = SM.mamba_mixer(p, cfg, x[:, t:t + 1], ssm_cache=c)
+        want.append(y)
+    np.testing.assert_allclose(got, jnp.concatenate(want, 1),
+                               rtol=1e-5, atol=1e-5)
+    for name in ("conv", "state"):
+        np.testing.assert_allclose(got_cache[name], c[name],
+                                   rtol=1e-5, atol=1e-5, err_msg=name)

@@ -165,6 +165,25 @@ def test_engine_spans_in_a_trace(tmp_path, engine_parts):
     assert all(d[3]["rows"] >= 1 for d in _named(events, "serve.decode"))
 
 
+def test_admission_records_one_pass(tmp_path, engine_parts):
+    """A prompt within the attention ring (16 slots at SMOKE) is admitted in
+    one forward, a longer one by the token loop: the ``serve.admit`` span
+    and the request's record say which."""
+    cfg, params, reg = engine_parts
+    eng = ServingEngine(params, cfg, reg, batch_slots=2, max_len=64)
+    rng = np.random.default_rng(0)
+    for rid, plen in (("fits", 16), ("longer", 20)):
+        eng.submit(Request(rid, rng.integers(0, cfg.vocab, plen), "c0",
+                           max_new_tokens=2))
+    events = _traced(tmp_path, eng.run)
+    admits = {a[3]["rid"]: a[3] for a in _named(events, "serve.admit")}
+    recs = {r.rid: r for r in eng.stats.ring("request")}
+    assert admits["fits"]["prompt_len"] == 16
+    assert bool(admits["fits"]["one_pass"]) and recs["fits"].one_pass
+    assert admits["longer"]["prompt_len"] == 20
+    assert not admits["longer"]["one_pass"] and not recs["longer"].one_pass
+
+
 def test_engine_records_are_bounded(engine_parts):
     cfg, params, reg = engine_parts
     eng = ServingEngine(params, cfg, reg, batch_slots=2, max_len=64)
