@@ -283,6 +283,9 @@ class CohortAggBuffer:
         push_quantized(q, scales, W, C, staleness, a)     int8 uplink
         finalize() -> (agg tree, divergence [G], cohort counts [G])
 
+    ``reduce`` gives one whole cohort's ``finalize`` result as a pure,
+    traceable function (the async server step compiles it).
+
     The row-blocked fusion leaf goes through ``kernels/cohort_agg`` —
     ``impl="pallas"`` runs the fused Pallas kernel (interpret-mode on CPU —
     auto-detected when ``interpret`` is None), ``impl="xla"`` its einsum
@@ -332,27 +335,96 @@ class CohortAggBuffer:
         self._cnt = self._zero_g
         self._pushes = 0
 
-    def _commit(self, treedef, agg_out, csum_out, sq: Array,
-                C: Array) -> None:
-        agg_tree = jax.tree_util.tree_unflatten(treedef, agg_out)
-        csum_tree = jax.tree_util.tree_unflatten(treedef, csum_out)
-        self._agg = jax.tree.map(jnp.add, self._agg, agg_tree)
-        self._csum = jax.tree.map(jnp.add, self._csum, csum_tree)
-        self._sq = self._sq + sq
-        self._cnt = self._cnt + jnp.sum(C, axis=0)
-
-    def push(self, deltas: Any, W: Array, C: Array) -> None:
-        """deltas: client-stacked pytree ([K, ...] leaves); W/C: [K, G]
-        combine weights and divergence-cohort mask for this chunk."""
-        from repro.kernels.cohort_agg import cohort_agg_divergence
-
-        layout = self.layout
+    def _count_push(self) -> None:
         if self.robust != "mean":
             if self._pushes > 0:
                 raise RuntimeError(
                     f"robust={self.robust!r} aggregation needs the whole "
                     "cohort in one push; chunked pushes are mean-only")
             self._pushes += 1
+
+    def _commit(self, agg: Any, csum: Any, sq: Array, cnt: Array) -> None:
+        self._agg = jax.tree.map(jnp.add, self._agg, agg)
+        self._csum = jax.tree.map(jnp.add, self._csum, csum)
+        self._sq = self._sq + sq
+        self._cnt = self._cnt + cnt
+
+    def push(self, deltas: Any, W: Array, C: Array) -> None:
+        """deltas: client-stacked pytree ([K, ...] leaves); W/C: [K, G]
+        combine weights and divergence-cohort mask for this chunk."""
+        self._count_push()
+        self._commit(*self._terms(deltas, W, C, self.bd))
+
+    def push_quantized(self, q: Any, scales: Any, W: Array, C: Array,
+                       staleness: Array | None = None,
+                       exponent: float = 0.0) -> None:
+        """One-pass compressed ingest: int8 client chunks, dequantized and
+        staleness-discounted inside the reduction.
+
+        q: client-stacked pytree ([K, ...] int8 leaves); scales: matching
+        [K] per-(client, leaf) dequant scales (``dist.quantize_int8_stacked``
+        layout). W/C: [K, G] as in ``push`` — W must be built with
+        ``cohort_weights(..., defer_scale=True)`` when the staleness
+        discount participates in normalization, because the effective weight
+        W * 1/(1+staleness)^exponent is applied *here*: on the fly inside
+        the fused kernel for the fusion leaf (the fp32 [K, D, r] stack is
+        never materialized), folded into the [K, G] einsum weights for
+        everything else.
+        """
+        self._count_push()
+        self._commit(*self._terms_quantized(q, scales, W, C, staleness,
+                                            exponent, self.bd))
+
+    def finalize(self) -> tuple[Any, Array, Array]:
+        """-> (aggregate tree, per-group divergence [G], cohort counts [G]).
+
+        Divergence uses the sufficient-statistics identity
+        E||d - mean||^2 = E||d||^2 - ||mean||^2 over each group's cohort.
+        """
+        return _finalize(self.layout, self._agg, self._csum, self._sq,
+                         self._cnt)
+
+    def reduce(self, deltas: Any, W: Array, C: Array, scales: Any = None,
+               staleness: Array | None = None, exponent: float = 0.0,
+               bd: int | None = None) -> tuple[Any, Array, Array]:
+        """One whole cohort in one pass: what ``reset``, one ``push`` (or
+        ``push_quantized`` when ``scales`` is given) and ``finalize`` give,
+        as a pure function of its inputs that leaves the accumulators
+        alone, so a compiled server step can trace it. ``bd`` overrides
+        the buffer's block size: resolve it with ``fusion_bd`` outside the
+        trace."""
+        bd = self.bd if bd is None else bd
+        if scales is None:
+            terms = self._terms(deltas, W, C, bd)
+        else:
+            terms = self._terms_quantized(deltas, scales, W, C, staleness,
+                                          exponent, bd)
+        return _finalize(self.layout, *terms)
+
+    def fusion_bd(self, deltas: Any, quant: bool) -> int | None:
+        """The kernel block size for this chunk's fusion leaf, resolved on
+        concrete shapes: the autotuner of compiled Pallas times real
+        arrays and cannot run inside a trace. ``self.bd`` where nothing
+        needs timing (it is snapped, or the XLA path ignores it)."""
+        if self.impl != "pallas" or self.bd is not None:
+            return self.bd
+        from repro.kernels.cohort_agg.ops import _resolve_bd
+        from repro.kernels.runtime import resolve_interpret
+
+        for path, leaf in jax.tree_util.tree_flatten_with_path(deltas)[0]:
+            if mdlora.path_str(path) == self.layout.fusion_a_path:
+                return _resolve_bd(leaf.shape, "pallas",
+                                   resolve_interpret(self.interpret), None,
+                                   quant)
+        return None
+
+    def _terms(self, deltas: Any, W: Array, C: Array, bd: int | None
+               ) -> tuple[Any, Any, Array, Array]:
+        """One chunk's (aggregate tree, cohort-sum tree, squared norms [G],
+        cohort counts [G]); pure."""
+        from repro.kernels.cohort_agg import cohort_agg_divergence
+
+        layout = self.layout
         W = jnp.asarray(W, jnp.float32)
         C = jnp.asarray(C, jnp.float32)
         leaves, treedef = jax.tree_util.tree_flatten_with_path(deltas)
@@ -365,7 +437,7 @@ class CohortAggBuffer:
                 rg_j = jnp.asarray(layout.row_group_vector(leaf.shape[1]))
                 agg_a, sq_rows, mean_rows, cnt_rows = cohort_agg_divergence(
                     x, W[:, rg_j], C[:, rg_j], impl=self.impl,
-                    interpret=self.interpret, bd=self.bd)
+                    interpret=self.interpret, bd=bd)
                 agg_out.append(agg_a)
                 csum_out.append(mean_rows * cnt_rows[:, None])
                 sq = sq.at[rg_j].add(sq_rows)
@@ -392,24 +464,15 @@ class CohortAggBuffer:
             agg_out = jax.tree_util.tree_flatten(robust_combine(
                 layout, deltas, W, self.robust, self.trim_frac,
                 self.krum_f))[0]
-        self._commit(treedef, agg_out, csum_out, sq, C)
+        return (jax.tree_util.tree_unflatten(treedef, agg_out),
+                jax.tree_util.tree_unflatten(treedef, csum_out), sq,
+                jnp.sum(C, axis=0))
 
-    def push_quantized(self, q: Any, scales: Any, W: Array, C: Array,
-                       staleness: Array | None = None,
-                       exponent: float = 0.0) -> None:
-        """One-pass compressed ingest: int8 client chunks, dequantized and
-        staleness-discounted inside the reduction.
-
-        q: client-stacked pytree ([K, ...] int8 leaves); scales: matching
-        [K] per-(client, leaf) dequant scales (``dist.quantize_int8_stacked``
-        layout). W/C: [K, G] as in ``push`` — W must be built with
-        ``cohort_weights(..., defer_scale=True)`` when the staleness
-        discount participates in normalization, because the effective weight
-        W * 1/(1+staleness)^exponent is applied *here*: on the fly inside
-        the fused kernel for the fusion leaf (the fp32 [K, D, r] stack is
-        never materialized), folded into the [K, G] einsum weights for
-        everything else.
-        """
+    def _terms_quantized(self, q: Any, scales: Any, W: Array, C: Array,
+                         staleness: Array | None, exponent: float,
+                         bd: int | None) -> tuple[Any, Any, Array, Array]:
+        """``_terms`` of an int8 chunk, discount and dequant scales applied
+        inside the reduction; pure."""
         from repro.kernels.cohort_agg import cohort_agg_divergence_quant
         from repro.kernels.cohort_agg.ref import staleness_discount_ref
 
@@ -429,8 +492,7 @@ class CohortAggBuffer:
             # einsum weights exactly). Costs one [K, ...] fp32 stack.
             from repro import dist
             x = dist.dequantize_int8_stacked(q, scales)
-            self.push(x, W * disc[:, None], C)
-            return
+            return self._terms(x, W * disc[:, None], C, bd)
         leaves, treedef = jax.tree_util.tree_flatten_with_path(q)
         scale_leaves = jax.tree.leaves(scales)
         agg_out, csum_out = [], []
@@ -443,8 +505,7 @@ class CohortAggBuffer:
                 agg_a, sq_rows, mean_rows, cnt_rows = (
                     cohort_agg_divergence_quant(
                         leaf, f, W[:, rg_j], C[:, rg_j], staleness, exponent,
-                        impl=self.impl, interpret=self.interpret,
-                        bd=self.bd))
+                        impl=self.impl, interpret=self.interpret, bd=bd))
                 agg_out.append(agg_a)
                 csum_out.append(mean_rows * cnt_rows[:, None])
                 sq = sq.at[rg_j].add(sq_rows)
@@ -472,20 +533,19 @@ class CohortAggBuffer:
             else:
                 agg_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
                 csum_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
-        self._commit(treedef, agg_out, csum_out, sq, C)
+        return (jax.tree_util.tree_unflatten(treedef, agg_out),
+                jax.tree_util.tree_unflatten(treedef, csum_out), sq,
+                jnp.sum(C, axis=0))
 
-    def finalize(self) -> tuple[Any, Array, Array]:
-        """-> (aggregate tree, per-group divergence [G], cohort counts [G]).
 
-        Divergence uses the sufficient-statistics identity
-        E||d - mean||^2 = E||d||^2 - ||mean||^2 over each group's cohort.
-        """
-        cnt = self._cnt
-        inv = 1.0 / jnp.maximum(cnt, 1.0)
-        mean_tree = mdlora.group_gate_tree(self.layout, self._csum, inv)
-        msq = mdlora.group_norms(self.layout, mean_tree)
-        d = jnp.where(cnt > 0, jnp.maximum(self._sq * inv - msq, 0.0), 0.0)
-        return self._agg, d, cnt
+def _finalize(layout: mdlora.GroupLayout, agg: Any, csum: Any, sq: Array,
+              cnt: Array) -> tuple[Any, Array, Array]:
+    """Accumulated sums -> (aggregate tree, divergence [G], counts [G])."""
+    inv = 1.0 / jnp.maximum(cnt, 1.0)
+    mean_tree = mdlora.group_gate_tree(layout, csum, inv)
+    msq = mdlora.group_norms(layout, mean_tree)
+    d = jnp.where(cnt > 0, jnp.maximum(sq * inv - msq, 0.0), 0.0)
+    return agg, d, cnt
 
 
 # ---------------------------------------------------------------------------

@@ -253,13 +253,76 @@ class _Pending:
     dropped: bool = False
 
 
+def make_server_step(layout: mdlora.GroupLayout, strategy: AsyncStrategy,
+                     fed: AsyncFedConfig, aggbuf: AG.CohortAggBuffer):
+    """The server half of a flush as one compiled program, built once per
+    run and reused by every flush:
+
+        server_step(trainable, deltas, scales, S, mmask_rows, staleness,
+                    fresh, bd=...) -> (new trainable, stats [4, G])
+
+    ``deltas`` is the client stack ([K, ...] leaves; the int8 codes when
+    ``scales``, its [K] dequant scales, is given), ``S`` [K, G] the uploaded
+    groups (0 for rows dropped as too stale), ``mmask_rows`` [K, M] the
+    modality masks the updates were trained with, ``staleness`` [K] and
+    ``fresh`` [K] the rows within ``max_staleness``. ``stats`` rows are the
+    divergence ``d``, the cohort counts ``cnt``, the mean squared group norm
+    of the uploaded rows ``mag`` and the groups any row uploaded ``sel``;
+    the caller reads them back in one transfer. ``bd`` is the fusion
+    kernel's block size, resolved outside the trace
+    (``CohortAggBuffer.fusion_bd``). Everything else static (treedef and
+    shapes, int8 or not, the aggregation rule and its robust reducer, the
+    kernel, the discount exponent, ``server_lr``) is fixed by the run or is
+    part of jit's own cache key.
+    """
+    a = strategy.staleness_exponent
+
+    def server_step(trainable, deltas, scales, S, mmask_rows, staleness,
+                    fresh, bd):
+        quant = scales is not None
+        trained = S.astype(jnp.float32)
+        mmask = mmask_rows.astype(jnp.float32)
+        scale = None if a == 0.0 else AG.staleness_discounts(staleness, a)
+        # quantized ingest applies the discount *inside* the fused
+        # reduction, so keep it out of the numerator (defer_scale)
+        wkw = dict(client_scale=scale, defer_scale=quant)
+        if strategy.agg == "cohort":
+            W = AG.cohort_weights(layout, trained, mmask, **wkw)
+        else:  # fedavg: every (fresh) buffered client into every non-empty
+            # group — max_staleness drops apply here too
+            ones = ((layout.sizes > 0)[None, :] & fresh[:, None]).astype(
+                jnp.float32)
+            W = AG.cohort_weights(layout, ones, jnp.ones_like(mmask), **wkw)
+        # divergence cohort: possession AND trained (paper Eq. 5 on the
+        # buffered subset)
+        C = (layout.accessible(mmask_rows, jnp) & (S > 0)).astype(
+            jnp.float32)
+        agg_tree, d, cnt = aggbuf.reduce(deltas, W, C, scales, staleness, a,
+                                         bd)
+        new = jax.tree.map(
+            lambda t, g: (t.astype(jnp.float32)
+                          + fed.server_lr * g).astype(t.dtype),
+            trainable, agg_tree)
+        # magnitude diagnostic over the buffer: the int8 codes times their
+        # scales fuse into the norms, no fp32 stack is kept
+        src = dist.dequantize_int8_stacked(deltas, scales) if quant else deltas
+        norms = jax.vmap(lambda t: mdlora.group_norms(layout, t))(src)
+        mag = (jnp.sum(norms * trained, axis=0)
+               / jnp.maximum(jnp.sum(trained, axis=0), 1.0))
+        sel = jnp.any(S > 0, axis=0).astype(jnp.float32)
+        return new, jnp.stack([d, cnt, mag, sel])
+
+    return jax.jit(server_step, static_argnames="bd")
+
+
 class _ServerFlushMixin:
     """The server-side flush, shared by both async runtimes.
 
-    Expects ``task/strategy/fleet/fed/state/trace/history/aggbuf``
-    attributes on self. ``aggbuf`` is the run-lifetime CohortAggBuffer —
-    hoisted out of the per-flush path and reset between flushes, so the
-    zero prototypes are derived exactly once per run.
+    Expects ``task/strategy/fleet/fed/state/trace/history/aggbuf/
+    server_step`` attributes on self. ``aggbuf`` is the run-lifetime
+    CohortAggBuffer whose reduction ``server_step`` (``make_server_step``)
+    compiles into one program with the cohort weights, the fold-in and the
+    statistics.
     """
 
     @property
@@ -267,109 +330,71 @@ class _ServerFlushMixin:
         """Simulated uplink cost per shipped parameter (int8 = 1 byte)."""
         return 1.0 if self.fed.uplink_codec == "int8" else 4.0
 
-    @obs.traced("fed.aggregate")
     def _flush_arrays(self, deltas: Any, S: np.ndarray,
                       client_ids: np.ndarray, losses: np.ndarray | None,
                       staleness: np.ndarray,
                       mmask_rows: np.ndarray | None = None) -> dict:
         """Fold one buffered cohort into the global model (one server
         version). ``deltas``: client-stacked pytree ([K, ...] leaves) or an
-        ``aggregation.QuantizedStack`` (int8 uplink — ingested through the
-        fused ``push_quantized`` path without rebuilding the fp32 stack),
+        ``aggregation.QuantizedStack`` (int8 uplink — ingested natively by
+        the compiled server step, the fp32 stack never rebuilt),
         rows aligned with ``S``/``client_ids``/``losses``/``staleness`` —
         all sorted by client id so a full homogeneous buffer reproduces the
         synchronous stack exactly. ``deltas=None`` = system-only flush
         (grad_mode "none"): staleness/energy accounting advances, the model
-        and divergence state stay untouched, loss records as NaN."""
-        task, fleet, fed = self.task, self.fleet, self.fed
-        layout, state = task.layout, self.state
-        K = len(client_ids)
-        quant = isinstance(deltas, AG.QuantizedStack)
-        staleness = np.asarray(staleness, np.float64)
-        # cohorts are per-flush: under a streaming schedule each buffered
-        # update carries the modality mask it was dispatched with, and both
-        # the Eq. 3-4 cohort weights and the Eq. 5 divergence cohorts below
-        # follow it instead of the fleet's static possession
-        if mmask_rows is None:
-            mmask_rows = fleet.modality_mask[client_ids]
-        fresh = np.ones(K, bool)
-        if self.strategy.max_staleness is not None:
-            fresh = staleness <= self.strategy.max_staleness
-            S = S * fresh[:, None]
+        and divergence state stay untouched, loss records as NaN. The
+        ``fed.aggregate`` span's ``program`` is 1 when the compiled server
+        step ran, 0 for the system-only flush."""
+        with obs.span("fed.aggregate", program=int(deltas is not None)):
+            fleet, fed = self.fleet, self.fed
+            layout, state = self.task.layout, self.state
+            staleness = np.asarray(staleness, np.float64)
+            # cohorts are per-flush: under a streaming schedule each
+            # buffered update carries the modality mask it was dispatched
+            # with, and both the Eq. 3-4 cohort weights and the Eq. 5
+            # divergence cohorts follow it instead of the fleet's static
+            # possession
+            if mmask_rows is None:
+                mmask_rows = fleet.modality_mask[client_ids]
+            fresh = np.ones(len(client_ids), bool)
+            if self.strategy.max_staleness is not None:
+                fresh = staleness <= self.strategy.max_staleness
+                S = S * fresh[:, None]
 
-        if deltas is not None:
-            with obs.span("fed.ingest"):
-                trained = jnp.asarray(S, jnp.float32)
-                mmask = jnp.asarray(mmask_rows, jnp.float32)
-                a = self.strategy.staleness_exponent
-                scale = (None if a == 0.0
-                         else AG.staleness_discounts(staleness, a))
-                # quantized ingest applies the discount *inside* the fused
-                # reduction, so keep it out of the numerator (defer_scale)
-                wkw = dict(client_scale=scale, defer_scale=quant)
-                if self.strategy.agg == "cohort":
-                    W = AG.cohort_weights(layout, trained, mmask, **wkw)
-                else:  # fedavg: every (fresh) buffered client into every
-                    # non-empty group — max_staleness drops apply here too
-                    ones = jnp.asarray(
-                        np.tile(layout.sizes[None, :] > 0, (K, 1))
-                        & fresh[:, None], jnp.float32)
-                    W = AG.cohort_weights(layout, ones, jnp.ones_like(mmask),
-                                          **wkw)
+            if deltas is not None:
+                with obs.span("fed.ingest"):
+                    quant = isinstance(deltas, AG.QuantizedStack)
+                    stack, scales = ((deltas.q, deltas.scales) if quant
+                                     else (deltas, None))
+                    state.trainable, stats = self.server_step(
+                        state.trainable, stack, scales,
+                        np.asarray(S, np.float32),
+                        np.asarray(mmask_rows, bool),
+                        staleness.astype(np.float32), fresh,
+                        bd=self.aggbuf.fusion_bd(stack, quant))
+                with obs.span("fed.stats"):  # the one host read, and EMAs
+                    d_np, cnt, mag, sel = jax.device_get(stats)
+                    t = cnt > 0
+                    state.dbar[t] = (fed.gamma * d_np
+                                     + (1.0 - fed.gamma) * state.dbar)[t]
+                    t = sel > 0
+                    state.mag_ema[t] = (0.5 * state.mag_ema + 0.5 * mag)[t]
+                    loss = float(np.mean(losses))
+            else:  # system-only simulation: no gradient work this flush
+                d_np = np.zeros(layout.G)
+                loss = float("nan")
 
-                # divergence cohort: possession AND trained (paper Eq. 5 on the
-                # buffered subset)
-                acc = layout.accessible(mmask_rows)
-                C = jnp.asarray(acc & (S > 0), jnp.float32)
-
-                self.aggbuf.reset()
-                if quant:
-                    self.aggbuf.push_quantized(
-                        deltas.q, deltas.scales, W, C,
-                        jnp.asarray(staleness, jnp.float32), a)
-                else:
-                    self.aggbuf.push(deltas, W, C)
-                agg_tree, d, cnt = self.aggbuf.finalize()
-
-                state.trainable = jax.tree.map(
-                    lambda t, g: (t.astype(jnp.float32)
-                                  + fed.server_lr * g).astype(t.dtype),
-                    state.trainable, agg_tree)
-
-            with obs.span("fed.stats"):
-                d_np = np.asarray(d)
-                touched = np.asarray(cnt) > 0
-                state.dbar[touched] = (fed.gamma * d_np
-                                       + (1.0 - fed.gamma) * state.dbar)[touched]
-                if quant:  # magnitude EMA diagnostic over the K-client buffer
-                    # (dequantizes [K, ...] for stats only — the hot reduction
-                    # above never materialized it)
-                    norm_src = dist.dequantize_int8_stacked(deltas.q,
-                                                            deltas.scales)
-                else:
-                    norm_src = deltas
-                per_client_norms = np.asarray(jax.vmap(
-                    lambda t: mdlora.group_norms(layout, t))(norm_src))
-                denom = np.maximum(S.sum(0), 1)
-                mag = (per_client_norms * S).sum(0) / denom
-                sel = S.any(0)
-                state.mag_ema[sel] = (0.5 * state.mag_ema + 0.5 * mag)[sel]
-                loss = float(np.mean(losses))
-        else:  # system-only simulation: no gradient work this flush
-            d_np = np.zeros(layout.G)
-            loss = float("nan")
-
-        state.round += 1
-        self.trace.flushes += 1
-        rec = {"flush": state.round, "sim_time_s": state.sim_time,
-               "loss": loss, "staleness_mean": float(staleness.mean()),
-               "energy_j": self.trace.energy_j,
-               "upload_mb": self.trace.upload_mb,
-               "selected_frac": float(S.mean()), "divergence": d_np}
-        for key in ("flush", "loss", "sim_time_s", "energy_j", "upload_mb",
-                    "staleness_mean", "selected_frac", "divergence"):
-            self.history[key].append(rec[key])
-        return rec
+            state.round += 1
+            self.trace.flushes += 1
+            rec = {"flush": state.round, "sim_time_s": state.sim_time,
+                   "loss": loss, "staleness_mean": float(staleness.mean()),
+                   "energy_j": self.trace.energy_j,
+                   "upload_mb": self.trace.upload_mb,
+                   "selected_frac": float(S.mean()), "divergence": d_np}
+            for key in ("flush", "loss", "sim_time_s", "energy_j", "upload_mb",
+                        "staleness_mean", "selected_frac", "divergence"):
+                self.history[key].append(rec[key])
+            return rec
 
     def _log_and_eval(self, rec: dict, dataset, log_every: int,
                       tag: str) -> None:
@@ -406,6 +431,7 @@ class AsyncFedRun(_ServerFlushMixin):
     history: dict
     aggbuf: AG.CohortAggBuffer
     proto: Any  # trainable prototype (explicit, not an id()-keyed cache)
+    server_step: Any  # make_server_step's compiled program
     # client-side error-feedback residuals (uplink_codec="int8"): the
     # quantization error stays on the device and is added to its next
     # update, so the compressed stream telescopes to the uncompressed one
@@ -435,6 +461,7 @@ class AsyncFedRun(_ServerFlushMixin):
                    make_local_update(task, fed, strategy.prox_mu),
                    _rank_gates(trainable0, strategy, fleet), EventQueue(),
                    [], trace, _history_init(), aggbuf, trainable0,
+                   make_server_step(task.layout, strategy, fed, aggbuf),
                    fx=_make_fault_runtime(fed, fleet), plan=plan)
 
     # -- client dispatch ------------------------------------------------------
@@ -611,7 +638,7 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                  state: AsyncFedState, local_update: Any, plan: AllocPlan,
                  fstate: FleetState, population: PopulationModel | None,
                  trace: AsyncTrace, history: dict,
-                 aggbuf: AG.CohortAggBuffer, proto: Any):
+                 aggbuf: AG.CohortAggBuffer, proto: Any, server_step: Any):
         self.task = task
         self.strategy = strategy
         self.fleet = fleet
@@ -625,6 +652,7 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
         self.history = history
         self.aggbuf = aggbuf
         self.proto = proto
+        self.server_step = server_step
         self.grad_mode = fed.grad_mode
         self.ring_clamped = 0  # cohort-mode pulls older than the ring
         # fault injection: drop/stall/corruption flags are drawn at dispatch
@@ -694,7 +722,8 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                                     krum_f=strategy.krum_f)
         return cls(task, strategy, fleet, fed, state, lu, plan,
                    FleetState.create(fleet.N), pop, trace, _history_init(),
-                   aggbuf, trainable0)
+                   aggbuf, trainable0,
+                   make_server_step(task.layout, strategy, fed, aggbuf))
 
     # -- client dispatch ------------------------------------------------------
 

@@ -61,16 +61,14 @@ class GroupLayout:
 
     # -- vectorized fleet helpers --------------------------------------------
 
-    def accessible(self, modality_mask: np.ndarray) -> np.ndarray:
-        """modality_mask: [N, M] -> accessible groups G_n: [N, G] bool."""
-        mm = np.asarray(modality_mask, bool)
-        out = np.zeros((mm.shape[0], self.G), bool)
-        for g in range(self.G):
-            if self.sizes[g] == 0:  # empty group (e.g. no B matrix in B1)
-                continue
-            m = self.modality[g]
-            out[:, g] = True if m < 0 else mm[:, m]
-        return out
+    def accessible(self, modality_mask: np.ndarray, xp=np) -> np.ndarray:
+        """modality_mask: [N, M] -> accessible groups G_n: [N, G] bool.
+        A group without a modality is open to all; an empty group (e.g. no
+        B matrix in B1) to none. ``xp=jnp`` traces it."""
+        mm = xp.asarray(modality_mask, bool)
+        src = xp.concatenate([mm, xp.ones((mm.shape[0], 1), bool)], axis=1)
+        col = np.where(self.modality < 0, mm.shape[1], self.modality)
+        return src[:, col] & (self.sizes > 0)[None, :]
 
     def mandatory(self, modality_mask: np.ndarray) -> np.ndarray:
         """Mandatory inclusion {A_m : m in M_n} (paper IV-B2b): [N, G]."""

@@ -269,6 +269,115 @@ def test_push_quantized_matches_push_dequantized(setup, impl):
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(ref_cnt))
 
 
+def _eager_flush(run, deltas, S, staleness, mmask_rows):
+    """The server flush as it ran eagerly, op by op: the oracle of the
+    compiled server step -> (trainable, d, cnt, dbar, mag_ema)."""
+    from repro import dist
+
+    layout, strat, fed = run.task.layout, run.strategy, run.fed
+    K = len(staleness)
+    quant = isinstance(deltas, AG.QuantizedStack)
+    fresh = np.ones(K, bool)
+    if strat.max_staleness is not None:
+        fresh = staleness <= strat.max_staleness
+        S = S * fresh[:, None]
+    trained = jnp.asarray(S, jnp.float32)
+    mmask = jnp.asarray(mmask_rows, jnp.float32)
+    a = strat.staleness_exponent
+    wkw = dict(client_scale=(None if a == 0.0
+                             else AG.staleness_discounts(staleness, a)),
+               defer_scale=quant)
+    if strat.agg == "cohort":
+        W = AG.cohort_weights(layout, trained, mmask, **wkw)
+    else:
+        ones = jnp.asarray(np.tile(layout.sizes[None, :] > 0, (K, 1))
+                           & fresh[:, None], jnp.float32)
+        W = AG.cohort_weights(layout, ones, jnp.ones_like(mmask), **wkw)
+    C = jnp.asarray(layout.accessible(mmask_rows) & (S > 0), jnp.float32)
+    buf = AG.CohortAggBuffer(layout, run.proto, impl=fed.agg_impl,
+                             interpret=True, robust=strat.robust)
+    if quant:
+        buf.push_quantized(deltas.q, deltas.scales, W, C,
+                           jnp.asarray(staleness, jnp.float32), a)
+        src = dist.dequantize_int8_stacked(deltas.q, deltas.scales)
+    else:
+        buf.push(deltas, W, C)
+        src = deltas
+    agg, d, cnt = buf.finalize()
+    tr = jax.tree.map(lambda t, g: t + fed.server_lr * g, run.proto, agg)
+    d, cnt = np.asarray(d), np.asarray(cnt)
+    dbar = np.ones(layout.G) * 1e-6
+    dbar[cnt > 0] = (fed.gamma * d + (1 - fed.gamma) * dbar)[cnt > 0]
+    norms = np.asarray(jax.vmap(lambda t: mdlora.group_norms(layout, t))(src))
+    mag = (norms * S).sum(0) / np.maximum(S.sum(0), 1)
+    mag_ema = np.ones(layout.G)
+    mag_ema[S.any(0)] = (0.5 * mag_ema + 0.5 * mag)[S.any(0)]
+    return tr, d, cnt, dbar, mag_ema
+
+
+@pytest.mark.parametrize("codec,agg,a,max_stale,robust,impl", [
+    ("int8", "cohort", 0.5, None, "mean", "xla"),
+    ("none", "cohort", 0.5, None, "mean", "xla"),
+    ("int8", "cohort", 0.0, None, "mean", "xla"),
+    ("none", "fedavg", 0.0, None, "mean", "xla"),
+    ("int8", "fedavg", 0.5, None, "mean", "xla"),
+    ("int8", "cohort", 0.5, 2, "mean", "xla"),
+    ("none", "fedavg", 0.5, 2, "mean", "xla"),
+    ("int8", "cohort", 0.5, None, "median", "xla"),
+    ("none", "cohort", 0.0, None, "median", "xla"),
+    ("int8", "cohort", 0.5, None, "mean", "pallas"),
+    ("none", "fedavg", 0.5, None, "mean", "pallas"),
+])
+def test_server_step_matches_eager_reduction(setup, codec, agg, a,
+                                             max_stale, robust, impl):
+    """The compiled server step (one program: cohort weights, fused ingest,
+    fold-in, divergence and magnitude statistics) == the eager reduction it
+    replaced, to fp32 tolerance."""
+    from repro import dist
+
+    _, task, tr0 = setup
+    layout = task.layout
+    mk = async_relief if agg == "cohort" else async_fedbuff
+    strat = mk(buffer_size=6, staleness_exponent=a, max_staleness=max_stale,
+               robust=robust)
+    fleet = make_fleet(3, 3, 2, M=4)
+    run = AsyncFedRun.create(task, tr0, strat, fleet, AsyncFedConfig(
+        rounds=1, eval_every=0, seed=0, uplink_codec=codec, agg_impl=impl,
+        agg_interpret=True))
+    rng = np.random.default_rng(7)
+    K = 6
+    deltas = jax.tree.map(
+        lambda x: jnp.asarray(rng.normal(size=(K,) + x.shape) * 1e-2,
+                              jnp.float32), tr0)
+    if codec == "int8":
+        deltas = AG.QuantizedStack(*dist.quantize_int8_stacked(deltas)[:2])
+    S = rng.random((K, layout.G)) > 0.3
+    staleness = rng.integers(0, 5, K).astype(np.float64)
+    mmask_rows = fleet.modality_mask[rng.integers(0, fleet.N, K)]
+    tr, d, cnt, dbar, mag_ema = _eager_flush(run, deltas, S.copy(),
+                                             staleness, mmask_rows)
+
+    rec = run._flush_arrays(deltas, S.copy(), np.arange(K), rng.random(K),
+                            staleness, mmask_rows=mmask_rows)
+    for x, y in zip(jax.tree.leaves(tr), jax.tree.leaves(run.state.trainable)):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(x), rtol=1e-5,
+                                   atol=1e-7)
+    # d = E|x|^2 - |Ex|^2 cancels: a one-member cohort leaves fp32 noise
+    # of the size of eps * |x|^2 (|x|^2 ~ 1e-2 here)
+    np.testing.assert_allclose(rec["divergence"], d, rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(run.state.dbar, dbar, rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(run.state.mag_ema, mag_ema, rtol=1e-5)
+    fresh = (staleness <= max_stale if max_stale is not None
+             else np.ones(K, bool))
+    stack = deltas.q if codec == "int8" else deltas
+    _, stats = run.server_step(
+        tr0, stack, deltas.scales if codec == "int8" else None,
+        (S * fresh[:, None]).astype(np.float32), mmask_rows,
+        staleness.astype(np.float32), fresh,
+        bd=run.aggbuf.fusion_bd(stack, codec == "int8"))
+    np.testing.assert_array_equal(np.asarray(stats[1]), cnt)
+
+
 def test_uplink_codec_int8_end_to_end(setup):
     """uplink_codec='int8' runs both runtimes with finite losses, ~4x less
     upload than fp32, heap/vectorized parity, and bounded model drift."""

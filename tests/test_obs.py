@@ -268,3 +268,40 @@ def test_flush_spans_in_a_trace(tmp_path):
                           ("fed.stats", "fed.aggregate")]:
         _assert_nested(events, child, parent)
     assert all(d[3]["B"] >= 1 for d in _named(events, "fed.dispatch"))
+
+
+def test_server_step_compiles_once_and_tags_the_flush(tmp_path):
+    """The flush's server half is one compiled program: the first flush
+    compiles it, later flushes at the same K compile nothing inside
+    ``fed.aggregate``, which carries ``program=1``; the system-only flush
+    (no deltas) stays eager, carries ``program=0`` and still advances the
+    server version."""
+    ds = make_har_dataset("pamap2", windows_per_subject=60, seed=0)
+    mm = mm_config_for("pamap2", backbone="cnn", d_feat=8, d_fused=32,
+                       cnn_ch=(8, 16))
+    task, tr0 = MMTask.create(mm, jax.random.PRNGKey(0))
+    fleet = scale_fleet(make_fleet(3, 3, 2, M=4), 32,
+                        np.random.default_rng(3))
+    kw = dict(rounds=1, local_epochs=1, steps_per_epoch=1, batch_size=4,
+              eval_every=0, seed=0, uplink_codec="int8")
+    run = VectorizedAsyncFedRun.create(
+        task, tr0, async_relief(buffer_size=8), fleet,
+        AsyncFedConfig(grad_mode="cohort", **kw))
+    sysrun = VectorizedAsyncFedRun.create(
+        task, tr0, async_relief(buffer_size=8), fleet,
+        AsyncFedConfig(grad_mode="none", **kw))
+    server = {"fed.aggregate", "fed.ingest", "fed.stats"}
+
+    def server_compiles(t0):
+        return [c for c in obs.compiles() if c[0] > t0 and c[1] in server]
+
+    t0 = obs.now()
+    run.run(ds, total_updates=8)
+    assert server_compiles(t0)  # the program, compiled on the first flush
+    t1 = obs.now()
+    events = _traced(tmp_path, lambda: (run.run(ds, total_updates=16),
+                                        sysrun.run(None, total_updates=8)))
+    assert server_compiles(t1) == []
+    assert run.state.round == 3 and sysrun.state.round == 1
+    assert [e[3]["program"] for e in _named(events, "fed.aggregate")] == [
+        1, 1, 0]
