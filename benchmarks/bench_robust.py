@@ -4,7 +4,7 @@ For each (cohort size K, Byzantine fraction, aggregator) cell, a cohort of
 K client deltas is drawn from the real model layout, a seeded fraction is
 corrupted through ``sim.faults.FaultModel`` (sign-flip at scale 100 — the
 gradient-inversion attack), and the cohort is reduced through the actual
-``CohortAggBuffer`` robust path. The figure of merit is the relative L2
+``cohort_reduce`` robust path. The figure of merit is the relative L2
 error of the aggregate against the honest-only oracle mean:
 
     rel_err = || agg(corrupted cohort) - mean(honest rows) ||
@@ -120,10 +120,9 @@ def _cell(task, tr0, k: int, byz_frac: float, trials: int,
             lay, jax.tree.map(lambda x: x[honest], deltas), W_h)
         denom = max(_tree_norm(oracle), 1e-9)
         for agg_kind in AGGREGATORS:
-            buf = AG.CohortAggBuffer(lay, tr0, robust=agg_kind,
-                                     trim_frac=TRIM_FRAC, krum_f=KRUM_F)
-            buf.push(corrupted, W, C)
-            agg, _, _ = buf.finalize()
+            agg, _, _ = AG.cohort_reduce(lay, corrupted, W, C,
+                                         robust=agg_kind,
+                                         trim_frac=TRIM_FRAC, krum_f=KRUM_F)
             errs[agg_kind].append(_tree_dist(agg, oracle) / denom)
     return errs
 

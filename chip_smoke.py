@@ -152,8 +152,8 @@ def flush_phase(seed: int, n_flushes: int = 3, min_deltas: int = 64,
         check(bool(np.isfinite(losses).all()), f"{name}: non-finite loss")
         rec[f"{name}_losses"] = losses.tolist()
     check(K >= min_deltas, f"flush stacks {K} deltas, want >= {min_deltas}")
-    check(run.aggbuf.impl == "pallas", "pallas run did not use the kernel")
-    interpret = resolve_interpret(run.aggbuf.interpret)
+    check(run.fed.agg_impl == "pallas", "pallas run did not use the kernel")
+    interpret = resolve_interpret(run.fed.agg_interpret)
     check(interpret == (jax.default_backend() == "cpu"),
           "kernel interprets off the CPU")
     rec["kernel_interpreted"] = interpret

@@ -8,6 +8,11 @@
   masked reduce over the client axis.
 * ``fedavg_weights``                   — naive FedAvg over all N participants
   (zero-padded deltas included): the paper's interference-prone baseline.
+* ``robust_combine``                   — Byzantine-robust within-cohort
+  location estimates (trimmed mean, coordinate median, blockwise Krum).
+* ``cohort_reduce``                    — the async server step's whole-cohort
+  reduction: Eq. 3 aggregate and Eq. 5 divergence statistics in one pass,
+  fp32 or int8 uplink, through the fused ``kernels/cohort_agg`` ingest.
 * ``lemma1_decomposition``             — the bias^2/variance/interference
   split of Lemma 1, used by diagnostics and tests.
 """
@@ -251,7 +256,7 @@ def robust_combine(layout: mdlora.GroupLayout, deltas: Any, W: Array,
 
 
 # ---------------------------------------------------------------------------
-# streaming cohort aggregation (async runtime / fleet-scale server)
+# the cohort reduction (async server step)
 # ---------------------------------------------------------------------------
 
 
@@ -259,9 +264,9 @@ def robust_combine(layout: mdlora.GroupLayout, deltas: Any, W: Array,
 class QuantizedStack:
     """A client-stacked int8 uplink payload: ``q`` leaves are [K, ...] int8
     and ``scales`` leaves are the matching [K] per-(client, leaf) dequant
-    scales, as produced by ``dist.quantize_int8_stacked``. The server flush
-    paths ingest this natively through ``CohortAggBuffer.push_quantized`` —
-    the fp32 client stack is never rebuilt in HBM."""
+    scales, as produced by ``dist.quantize_int8_stacked``. The server step
+    ingests this natively through ``cohort_reduce(..., scales=)`` — the
+    fp32 client stack is never rebuilt in HBM."""
     q: Any
     scales: Any
 
@@ -270,282 +275,150 @@ class QuantizedStack:
         return jax.tree.leaves(self.q)[0].shape[0]
 
 
-class CohortAggBuffer:
-    """Streaming/accumulating variant of the fused cohort-agg reduction.
+def cohort_reduce(layout: mdlora.GroupLayout, deltas: Any, W: Array,
+                  C: Array, *, scales: Any = None,
+                  staleness: Array | None = None, exponent: float = 0.0,
+                  robust: str = "mean", trim_frac: float = 0.1,
+                  krum_f: int = 1, impl: str = "xla",
+                  interpret: bool | None = None, bd: int | None = None
+                  ) -> tuple[Any, Array, Array]:
+    """One whole cohort in one pass -> (aggregate tree, per-group
+    divergence [G], cohort counts [G]). Pure, so the async server step
+    compiles it.
 
-    The synchronous engine materializes the full [N, ...] delta stack and
-    reduces it in one shot; the async runtime receives *partial buffers*
-    (FedBuff cohorts of K clients) and at fleet scale even a sync server
-    would stream arrivals. This class accumulates Eq. 3 aggregates and the
-    Eq. 5 divergence sufficient statistics chunk by chunk:
+    deltas: client-stacked pytree ([K, ...] leaves); W/C: [K, G] Eq. 3-4
+    combine weights and Eq. 5 divergence-cohort mask. With ``scales`` (the
+    [K] per-(client, leaf) dequant scales of ``dist.quantize_int8_stacked``)
+    the leaves are int8 codes, and the dequant scale and the staleness
+    discount 1/(1+staleness)^exponent are applied inside the reduction: on
+    the fly in the fused kernel for the fusion leaf (the fp32 [K, D, r]
+    stack is never materialized), folded into the [K, G] einsum weights
+    everywhere else. W must then come from ``cohort_weights(...,
+    defer_scale=True)`` when the discount takes part in the normalization.
 
-        push(deltas [K,...], W [K,G], C [K,G])            fp32 uplink
-        push_quantized(q, scales, W, C, staleness, a)     int8 uplink
-        finalize() -> (agg tree, divergence [G], cohort counts [G])
+    The row-blocked fusion leaf goes through ``kernels/cohort_agg``:
+    ``impl="pallas"`` runs the fused Pallas kernel (interpret mode on the
+    CPU when ``interpret`` is None), ``impl="xla"`` its einsum oracle; both
+    give the aggregate and the per-row (sqsum, mean, count) statistics in
+    one pass. ``bd`` is the kernel's block size: resolve it with
+    ``fusion_block`` outside a trace (None lets the kernel choose, which
+    needs concrete arrays under Pallas). The other leaves use the masked
+    einsums of ``weighted_combine``. Divergence uses the
+    sufficient-statistics identity E||d - mean||^2 = E||d||^2 - ||mean||^2
+    over each group's cohort; an empty cohort gives a zero aggregate and
+    zero divergence (a frozen block), never NaN.
 
-    ``reduce`` gives one whole cohort's ``finalize`` result as a pure,
-    traceable function (the async server step compiles it).
-
-    The row-blocked fusion leaf goes through ``kernels/cohort_agg`` —
-    ``impl="pallas"`` runs the fused Pallas kernel (interpret-mode on CPU —
-    auto-detected when ``interpret`` is None), ``impl="xla"`` its einsum
-    oracle; both produce the aggregate and the per-row (sqsum, mean, count)
-    stats in one pass over the chunk. ``bd=None`` autotunes the kernel block
-    size per shape; explicit values snap to the largest divisor of D, so
-    blocking survives non-divisible row dimensions. All other leaves use the
-    same masked einsum reductions as ``weighted_combine``. Empty cohorts
-    finalize to zero aggregate and zero divergence (frozen block), never
-    NaN.
-
-    ``robust`` selects the within-cohort location estimate for the
-    *aggregate* ("mean" | "trimmed" | "median" | "krum"); divergence stats
-    are always the plain Eq. 5 sufficient statistics. Order statistics do
-    not stream — robust modes require exactly one ``push`` per finalize
-    (the async runtime flushes whole FedBuff cohorts, so this holds there
-    by construction) and a second chunked push raises.
+    ``robust`` picks the within-cohort location estimate for the aggregate
+    only (``robust_combine``); the divergence statistics stay the plain
+    Eq. 5 ones. Order statistics cannot be taken over int8 codes with
+    per-client scales, so a robust int8 cohort is dequantized first, with
+    the discount folded into W (one [K, ...] fp32 stack).
     """
+    from repro.kernels.cohort_agg import (cohort_agg_divergence,
+                                          cohort_agg_divergence_quant)
 
-    def __init__(self, layout: mdlora.GroupLayout, proto: Any,
-                 impl: str = "xla", interpret: bool | None = None,
-                 bd: int | None = None, robust: str = "mean",
-                 trim_frac: float = 0.1, krum_f: int = 1):
-        if robust not in ROBUST_AGGREGATORS:
-            raise ValueError(f"robust must be one of {ROBUST_AGGREGATORS}, "
-                             f"got {robust!r}")
-        self.layout = layout
-        self.impl = impl
-        self.interpret = interpret
-        self.bd = bd
-        self.robust = robust
-        self.trim_frac = trim_frac
-        self.krum_f = krum_f
-        # zero prototypes are derived once; reset() re-points the
-        # accumulators at them (jnp arrays are immutable, sharing is safe),
-        # so a long-lived buffer serves many flushes without re-allocating
-        self._zero_tree = jax.tree.map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), proto)
-        self._zero_g = jnp.zeros((layout.G,), jnp.float32)
-        self.reset()
-
-    def reset(self) -> None:
-        """Clear accumulated state so the buffer can serve the next flush."""
-        self._agg = self._zero_tree
-        self._csum = self._zero_tree
-        self._sq = self._zero_g
-        self._cnt = self._zero_g
-        self._pushes = 0
-
-    def _count_push(self) -> None:
-        if self.robust != "mean":
-            if self._pushes > 0:
-                raise RuntimeError(
-                    f"robust={self.robust!r} aggregation needs the whole "
-                    "cohort in one push; chunked pushes are mean-only")
-            self._pushes += 1
-
-    def _commit(self, agg: Any, csum: Any, sq: Array, cnt: Array) -> None:
-        self._agg = jax.tree.map(jnp.add, self._agg, agg)
-        self._csum = jax.tree.map(jnp.add, self._csum, csum)
-        self._sq = self._sq + sq
-        self._cnt = self._cnt + cnt
-
-    def push(self, deltas: Any, W: Array, C: Array) -> None:
-        """deltas: client-stacked pytree ([K, ...] leaves); W/C: [K, G]
-        combine weights and divergence-cohort mask for this chunk."""
-        self._count_push()
-        self._commit(*self._terms(deltas, W, C, self.bd))
-
-    def push_quantized(self, q: Any, scales: Any, W: Array, C: Array,
-                       staleness: Array | None = None,
-                       exponent: float = 0.0) -> None:
-        """One-pass compressed ingest: int8 client chunks, dequantized and
-        staleness-discounted inside the reduction.
-
-        q: client-stacked pytree ([K, ...] int8 leaves); scales: matching
-        [K] per-(client, leaf) dequant scales (``dist.quantize_int8_stacked``
-        layout). W/C: [K, G] as in ``push`` — W must be built with
-        ``cohort_weights(..., defer_scale=True)`` when the staleness
-        discount participates in normalization, because the effective weight
-        W * 1/(1+staleness)^exponent is applied *here*: on the fly inside
-        the fused kernel for the fusion leaf (the fp32 [K, D, r] stack is
-        never materialized), folded into the [K, G] einsum weights for
-        everything else.
-        """
-        self._count_push()
-        self._commit(*self._terms_quantized(q, scales, W, C, staleness,
-                                            exponent, self.bd))
-
-    def finalize(self) -> tuple[Any, Array, Array]:
-        """-> (aggregate tree, per-group divergence [G], cohort counts [G]).
-
-        Divergence uses the sufficient-statistics identity
-        E||d - mean||^2 = E||d||^2 - ||mean||^2 over each group's cohort.
-        """
-        return _finalize(self.layout, self._agg, self._csum, self._sq,
-                         self._cnt)
-
-    def reduce(self, deltas: Any, W: Array, C: Array, scales: Any = None,
-               staleness: Array | None = None, exponent: float = 0.0,
-               bd: int | None = None) -> tuple[Any, Array, Array]:
-        """One whole cohort in one pass: what ``reset``, one ``push`` (or
-        ``push_quantized`` when ``scales`` is given) and ``finalize`` give,
-        as a pure function of its inputs that leaves the accumulators
-        alone, so a compiled server step can trace it. ``bd`` overrides
-        the buffer's block size: resolve it with ``fusion_bd`` outside the
-        trace."""
-        bd = self.bd if bd is None else bd
-        if scales is None:
-            terms = self._terms(deltas, W, C, bd)
-        else:
-            terms = self._terms_quantized(deltas, scales, W, C, staleness,
-                                          exponent, bd)
-        return _finalize(self.layout, *terms)
-
-    def fusion_bd(self, deltas: Any, quant: bool) -> int | None:
-        """The kernel block size for this chunk's fusion leaf, resolved on
-        concrete shapes: the autotuner of compiled Pallas times real
-        arrays and cannot run inside a trace. ``self.bd`` where nothing
-        needs timing (it is snapped, or the XLA path ignores it)."""
-        if self.impl != "pallas" or self.bd is not None:
-            return self.bd
-        from repro.kernels.cohort_agg.ops import _resolve_bd
-        from repro.kernels.runtime import resolve_interpret
-
-        for path, leaf in jax.tree_util.tree_flatten_with_path(deltas)[0]:
-            if mdlora.path_str(path) == self.layout.fusion_a_path:
-                return _resolve_bd(leaf.shape, "pallas",
-                                   resolve_interpret(self.interpret), None,
-                                   quant)
-        return None
-
-    def _terms(self, deltas: Any, W: Array, C: Array, bd: int | None
-               ) -> tuple[Any, Any, Array, Array]:
-        """One chunk's (aggregate tree, cohort-sum tree, squared norms [G],
-        cohort counts [G]); pure."""
-        from repro.kernels.cohort_agg import cohort_agg_divergence
-
-        layout = self.layout
-        W = jnp.asarray(W, jnp.float32)
-        C = jnp.asarray(C, jnp.float32)
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(deltas)
-        agg_out, csum_out = [], []
-        sq = jnp.zeros((layout.G,), jnp.float32)
-        for path, leaf in leaves:
-            p = mdlora.path_str(path)
-            x = leaf.astype(jnp.float32)
-            if p == layout.fusion_a_path:
-                rg_j = jnp.asarray(layout.row_group_vector(leaf.shape[1]))
-                agg_a, sq_rows, mean_rows, cnt_rows = cohort_agg_divergence(
-                    x, W[:, rg_j], C[:, rg_j], impl=self.impl,
-                    interpret=self.interpret, bd=bd)
-                agg_out.append(agg_a)
-                csum_out.append(mean_rows * cnt_rows[:, None])
-                sq = sq.at[rg_j].add(sq_rows)
-            elif p in layout.leaf_axis0_groups:
-                ids = jnp.asarray(layout.leaf_axis0_groups[p])
-                agg_out.append(jnp.einsum("nl,nl...->l...", W[:, ids], x))
-                csum_out.append(jnp.einsum("nl,nl...->l...", C[:, ids], x))
-                per_l = jnp.sum(jnp.square(x),
-                                axis=tuple(range(2, x.ndim)))  # [K, L]
-                sq = sq.at[ids].add(jnp.sum(per_l * C[:, ids], axis=0))
-            elif p in layout.leaf_group:
-                g = layout.leaf_group[p]
-                agg_out.append(jnp.einsum("n,n...->...", W[:, g], x))
-                csum_out.append(jnp.einsum("n,n...->...", C[:, g], x))
-                per_n = jnp.sum(jnp.square(x),
-                                axis=tuple(range(1, x.ndim)))  # [K]
-                sq = sq.at[g].add(jnp.sum(per_n * C[:, g]))
-            else:
-                agg_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
-                csum_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
-        if self.robust != "mean":
-            # divergence stats above stay the plain sufficient statistics;
-            # only the aggregate is swapped for the robust estimate
-            agg_out = jax.tree_util.tree_flatten(robust_combine(
-                layout, deltas, W, self.robust, self.trim_frac,
-                self.krum_f))[0]
-        return (jax.tree_util.tree_unflatten(treedef, agg_out),
-                jax.tree_util.tree_unflatten(treedef, csum_out), sq,
-                jnp.sum(C, axis=0))
-
-    def _terms_quantized(self, q: Any, scales: Any, W: Array, C: Array,
-                         staleness: Array | None, exponent: float,
-                         bd: int | None) -> tuple[Any, Any, Array, Array]:
-        """``_terms`` of an int8 chunk, discount and dequant scales applied
-        inside the reduction; pure."""
-        from repro.kernels.cohort_agg import cohort_agg_divergence_quant
+    W = jnp.asarray(W, jnp.float32)
+    C = jnp.asarray(C, jnp.float32)
+    if scales is not None:
         from repro.kernels.cohort_agg.ref import staleness_discount_ref
 
-        layout = self.layout
-        W = jnp.asarray(W, jnp.float32)
-        C = jnp.asarray(C, jnp.float32)
         if staleness is None:
             staleness = jnp.zeros((W.shape[0],), jnp.float32)
         staleness = jnp.asarray(staleness, jnp.float32)
         disc = staleness_discount_ref(staleness, exponent)
-        if self.robust != "mean":
-            # Order statistics cannot be taken over int8 codes with
-            # per-client scales, so the fused compressed ingest does not
-            # apply: dequantize the chunk and take the fp32 path, folding
-            # the staleness discount into the weights up front (the dequant
-            # scale f rides along in x, so W*disc*f matches the fused
-            # einsum weights exactly). Costs one [K, ...] fp32 stack.
+        if robust != "mean":
+            # the dequant scale rides along in the fp32 stack, so W * disc
+            # matches the fused einsum weights W * disc * f exactly
             from repro import dist
-            x = dist.dequantize_int8_stacked(q, scales)
-            return self._terms(x, W * disc[:, None], C, bd)
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(q)
-        scale_leaves = jax.tree.leaves(scales)
-        agg_out, csum_out = [], []
-        sq = jnp.zeros((layout.G,), jnp.float32)
-        for (path, leaf), f in zip(leaves, scale_leaves):
-            p = mdlora.path_str(path)
+            deltas = dist.dequantize_int8_stacked(deltas, scales)
+            W, scales = W * disc[:, None], None
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(deltas)
+    f_leaves = ([None] * len(leaves) if scales is None
+                else jax.tree.leaves(scales))
+    agg_out, csum_out = [], []
+    sq = jnp.zeros((layout.G,), jnp.float32)
+    for (path, leaf), f in zip(leaves, f_leaves):
+        p = mdlora.path_str(path)
+        if f is not None:
             f = jnp.asarray(f, jnp.float32)  # [K] dequant scales
-            if p == layout.fusion_a_path:
-                rg_j = jnp.asarray(layout.row_group_vector(leaf.shape[1]))
-                agg_a, sq_rows, mean_rows, cnt_rows = (
-                    cohort_agg_divergence_quant(
-                        leaf, f, W[:, rg_j], C[:, rg_j], staleness, exponent,
-                        impl=self.impl, interpret=self.interpret, bd=bd))
-                agg_out.append(agg_a)
-                csum_out.append(mean_rows * cnt_rows[:, None])
-                sq = sq.at[rg_j].add(sq_rows)
-            elif p in layout.leaf_axis0_groups:
-                ids = jnp.asarray(layout.leaf_axis0_groups[p])
-                x = leaf.astype(jnp.float32)
-                agg_out.append(jnp.einsum("nl,nl...->l...",
-                                          W[:, ids] * (disc * f)[:, None],
-                                          x))
-                csum_out.append(jnp.einsum("nl,nl...->l...",
-                                           C[:, ids] * f[:, None], x))
-                per_l = jnp.sum(jnp.square(x),
-                                axis=tuple(range(2, x.ndim)))  # [K, L]
-                sq = sq.at[ids].add(jnp.sum(
-                    per_l * C[:, ids] * jnp.square(f)[:, None], axis=0))
-            elif p in layout.leaf_group:
-                g = layout.leaf_group[p]
-                x = leaf.astype(jnp.float32)
-                agg_out.append(jnp.einsum("n,n...->...", W[:, g] * disc * f,
-                                          x))
-                csum_out.append(jnp.einsum("n,n...->...", C[:, g] * f, x))
-                per_n = jnp.sum(jnp.square(x),
-                                axis=tuple(range(1, x.ndim)))  # [K]
-                sq = sq.at[g].add(jnp.sum(per_n * C[:, g] * jnp.square(f)))
+        if p == layout.fusion_a_path:
+            rg_j = jnp.asarray(layout.row_group_vector(leaf.shape[1]))
+            if f is None:
+                terms = cohort_agg_divergence(
+                    leaf.astype(jnp.float32), W[:, rg_j], C[:, rg_j],
+                    impl=impl, interpret=interpret, bd=bd)
             else:
-                agg_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
-                csum_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
-        return (jax.tree_util.tree_unflatten(treedef, agg_out),
-                jax.tree_util.tree_unflatten(treedef, csum_out), sq,
-                jnp.sum(C, axis=0))
-
-
-def _finalize(layout: mdlora.GroupLayout, agg: Any, csum: Any, sq: Array,
-              cnt: Array) -> tuple[Any, Array, Array]:
-    """Accumulated sums -> (aggregate tree, divergence [G], counts [G])."""
+                terms = cohort_agg_divergence_quant(
+                    leaf, f, W[:, rg_j], C[:, rg_j], staleness, exponent,
+                    impl=impl, interpret=interpret, bd=bd)
+            agg_a, sq_rows, mean_rows, cnt_rows = terms
+            agg_out.append(agg_a)
+            csum_out.append(mean_rows * cnt_rows[:, None])
+            sq = sq.at[rg_j].add(sq_rows)
+        elif p in layout.leaf_axis0_groups:
+            ids = jnp.asarray(layout.leaf_axis0_groups[p])
+            x = leaf.astype(jnp.float32)
+            w = W[:, ids]
+            if f is not None:
+                w = w * (disc * f)[:, None]
+            agg_out.append(jnp.einsum("nl,nl...->l...", w, x))
+            c = C[:, ids]
+            if f is not None:
+                c = c * f[:, None]
+            csum_out.append(jnp.einsum("nl,nl...->l...", c, x))
+            per_l = jnp.sum(jnp.square(x),
+                            axis=tuple(range(2, x.ndim)))  # [K, L]
+            m = per_l * C[:, ids]
+            if f is not None:
+                m = m * jnp.square(f)[:, None]
+            sq = sq.at[ids].add(jnp.sum(m, axis=0))
+        elif p in layout.leaf_group:
+            g = layout.leaf_group[p]
+            x = leaf.astype(jnp.float32)
+            w = W[:, g]
+            if f is not None:
+                w = w * disc * f
+            agg_out.append(jnp.einsum("n,n...->...", w, x))
+            c = C[:, g]
+            if f is not None:
+                c = c * f
+            csum_out.append(jnp.einsum("n,n...->...", c, x))
+            per_n = jnp.sum(jnp.square(x),
+                            axis=tuple(range(1, x.ndim)))  # [K]
+            m = per_n * C[:, g]
+            if f is not None:
+                m = m * jnp.square(f)
+            sq = sq.at[g].add(jnp.sum(m))
+        else:
+            agg_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
+            csum_out.append(jnp.zeros(leaf.shape[1:], jnp.float32))
+    if robust != "mean":
+        agg_out = jax.tree_util.tree_flatten(robust_combine(
+            layout, deltas, W, robust, trim_frac, krum_f))[0]
+    cnt = jnp.sum(C, axis=0)
     inv = 1.0 / jnp.maximum(cnt, 1.0)
-    mean_tree = mdlora.group_gate_tree(layout, csum, inv)
+    mean_tree = mdlora.group_gate_tree(
+        layout, jax.tree_util.tree_unflatten(treedef, csum_out), inv)
     msq = mdlora.group_norms(layout, mean_tree)
     d = jnp.where(cnt > 0, jnp.maximum(sq * inv - msq, 0.0), 0.0)
-    return agg, d, cnt
+    return jax.tree_util.tree_unflatten(treedef, agg_out), d, cnt
+
+
+def fusion_block(layout: mdlora.GroupLayout, deltas: Any, *, quant: bool,
+                 impl: str, interpret: bool | None) -> int | None:
+    """The fusion kernel's block size for ``cohort_reduce``, resolved on
+    concrete arrays: the autotuner of compiled Pallas times real arrays and
+    cannot run inside a trace. None on the XLA path, which has no blocks."""
+    if impl != "pallas":
+        return None
+    from repro.kernels.cohort_agg.ops import _resolve_bd
+    from repro.kernels.runtime import resolve_interpret
+
+    for path, leaf in jax.tree_util.tree_flatten_with_path(deltas)[0]:
+        if mdlora.path_str(path) == layout.fusion_a_path:
+            return _resolve_bd(leaf.shape, "pallas",
+                               resolve_interpret(interpret), None, quant)
+    return None
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ cohort aggregation*:
   * each buffered update is discounted by 1/(1+s)^a where s counts server
     versions elapsed since the client pulled (strategies.AsyncStrategy);
   * aggregation reuses the mdlora.GroupLayout block interface through the
-    streaming ``aggregation.CohortAggBuffer``, so rare-modality blocks still
+    whole-cohort ``aggregation.cohort_reduce``, so rare-modality blocks still
     aggregate only within their possession cohort and an empty cohort
     freezes its block — no interference, no NaNs, no matter which subset of
     the fleet happens to sit in the buffer.
@@ -96,7 +96,7 @@ class AsyncFedConfig(FedConfig):
     # uplink codec: "none" ships fp32 deltas; "int8" quantizes client-side
     # (dist.quantize_int8 + error feedback) and the server ingests the
     # compressed payload natively — dequantization and the staleness
-    # discount are fused into the cohort reduction (push_quantized), the
+    # discount are fused into the cohort reduction (cohort_reduce), the
     # fp32 client stack is never rebuilt, and upload bytes drop 4x.
     uplink_codec: str = "none"
     # --- vectorized fleet runtime (VectorizedAsyncFedRun) ---
@@ -254,7 +254,7 @@ class _Pending:
 
 
 def make_server_step(layout: mdlora.GroupLayout, strategy: AsyncStrategy,
-                     fed: AsyncFedConfig, aggbuf: AG.CohortAggBuffer):
+                     fed: AsyncFedConfig):
     """The server half of a flush as one compiled program, built once per
     run and reused by every flush:
 
@@ -270,7 +270,7 @@ def make_server_step(layout: mdlora.GroupLayout, strategy: AsyncStrategy,
     of the uploaded rows ``mag`` and the groups any row uploaded ``sel``;
     the caller reads them back in one transfer. ``bd`` is the fusion
     kernel's block size, resolved outside the trace
-    (``CohortAggBuffer.fusion_bd``). Everything else static (treedef and
+    (``aggregation.fusion_block``). Everything else static (treedef and
     shapes, int8 or not, the aggregation rule and its robust reducer, the
     kernel, the discount exponent, ``server_lr``) is fixed by the run or is
     part of jit's own cache key.
@@ -297,8 +297,11 @@ def make_server_step(layout: mdlora.GroupLayout, strategy: AsyncStrategy,
         # buffered subset)
         C = (layout.accessible(mmask_rows, jnp) & (S > 0)).astype(
             jnp.float32)
-        agg_tree, d, cnt = aggbuf.reduce(deltas, W, C, scales, staleness, a,
-                                         bd)
+        agg_tree, d, cnt = AG.cohort_reduce(
+            layout, deltas, W, C, scales=scales, staleness=staleness,
+            exponent=a, robust=strategy.robust, trim_frac=strategy.trim_frac,
+            krum_f=strategy.krum_f, impl=fed.agg_impl,
+            interpret=fed.agg_interpret, bd=bd)
         new = jax.tree.map(
             lambda t, g: (t.astype(jnp.float32)
                           + fed.server_lr * g).astype(t.dtype),
@@ -316,14 +319,29 @@ def make_server_step(layout: mdlora.GroupLayout, strategy: AsyncStrategy,
 
 
 class _ServerFlushMixin:
-    """The server-side flush, shared by both async runtimes.
+    """What both async runtimes share: a dispatch's allocation under a live
+    modality schedule, and the server-side flush.
 
-    Expects ``task/strategy/fleet/fed/state/trace/history/aggbuf/
-    server_step`` attributes on self. ``aggbuf`` is the run-lifetime
-    CohortAggBuffer whose reduction ``server_step`` (``make_server_step``)
-    compiles into one program with the cohort weights, the fold-in and the
-    statistics.
+    Expects ``task/strategy/fleet/fed/state/plan/trace/history/
+    server_step`` attributes on self. ``server_step`` is the run's
+    compiled server program (``make_server_step``): the cohort weights,
+    the cohort reduction, the fold-in and the statistics.
     """
+
+    def _allocate(self, idx: np.ndarray, live_mm: np.ndarray) -> np.ndarray:
+        """S rows [len(idx), G] for a dispatch, from the run's AllocPlan.
+        Under a modality schedule the candidates (for modality-aware
+        allocators) and the mandatory blocks follow ``live_mm``, the [B, M]
+        masks live at dispatch time; the Eq. 7 budgets stay the plan's."""
+        if self.fed.modality_schedule is None:
+            return allocate_rows(self.plan, self.strategy, self.state, idx)
+        layout = self.task.layout
+        unaware = self.strategy.alloc in ("full", "magnitude", "depth")
+        return allocate_rows(
+            self.plan, self.strategy, self.state, idx,
+            cand=None if unaware else layout.accessible(live_mm),
+            mandatory=(layout.mandatory(live_mm)
+                       if self.strategy.mandatory else None))
 
     @property
     def _uplink_bytes_per_param(self) -> float:
@@ -371,7 +389,9 @@ class _ServerFlushMixin:
                         np.asarray(S, np.float32),
                         np.asarray(mmask_rows, bool),
                         staleness.astype(np.float32), fresh,
-                        bd=self.aggbuf.fusion_bd(stack, quant))
+                        bd=AG.fusion_block(layout, stack, quant=quant,
+                                           impl=fed.agg_impl,
+                                           interpret=fed.agg_interpret))
                 with obs.span("fed.stats"):  # the one host read, and EMAs
                     d_np, cnt, mag, sel = jax.device_get(stats)
                     t = cnt > 0
@@ -429,7 +449,6 @@ class AsyncFedRun(_ServerFlushMixin):
     buffer: list
     trace: AsyncTrace
     history: dict
-    aggbuf: AG.CohortAggBuffer
     proto: Any  # trainable prototype (explicit, not an id()-keyed cache)
     server_step: Any  # make_server_step's compiled program
     # client-side error-feedback residuals (uplink_codec="int8"): the
@@ -449,19 +468,13 @@ class AsyncFedRun(_ServerFlushMixin):
         state = _make_state(task.layout.G, trainable0, fed.seed)
         trace = AsyncTrace()
         trace.init_fleet(fleet.N)
-        aggbuf = AG.CohortAggBuffer(task.layout, trainable0,
-                                    impl=fed.agg_impl,
-                                    interpret=fed.agg_interpret,
-                                    robust=strategy.robust,
-                                    trim_frac=strategy.trim_frac,
-                                    krum_f=strategy.krum_f)
         plan = (plan_allocation(strategy, task, fleet, fed, task.layout.flops)
                 if strategy.alloc != "random" else None)
         return cls(task, strategy, fleet, fed, state,
                    make_local_update(task, fed, strategy.prox_mu),
                    _rank_gates(trainable0, strategy, fleet), EventQueue(),
-                   [], trace, _history_init(), aggbuf, trainable0,
-                   make_server_step(task.layout, strategy, fed, aggbuf),
+                   [], trace, _history_init(), trainable0,
+                   make_server_step(task.layout, strategy, fed),
                    fx=_make_fault_runtime(fed, fleet), plan=plan)
 
     # -- client dispatch ------------------------------------------------------
@@ -483,17 +496,8 @@ class AsyncFedRun(_ServerFlushMixin):
             S_full, _ = allocate(self.strategy, state, task, fleet, fed,
                                  layout.flops)
             S = S_full[clients]  # [K, G]
-        elif sched is not None:
-            # time-varying masks: allocation candidates follow the LIVE
-            # accessibility at dispatch time (budgets stay plan-static)
-            unaware = self.strategy.alloc in ("full", "magnitude", "depth")
-            S = allocate_rows(
-                self.plan, self.strategy, state, clients,
-                cand=None if unaware else layout.accessible(live_mm),
-                mandatory=(layout.mandatory(live_mm)
-                           if self.strategy.mandatory else None))
         else:
-            S = allocate_rows(self.plan, self.strategy, state, clients)
+            S = self._allocate(clients, live_mm)
         fault = (self.fx.on_dispatch(clients)
                  if self.fx is not None else None)
 
@@ -516,15 +520,8 @@ class AsyncFedRun(_ServerFlushMixin):
                                      self.strategy.comm_budget)
             deltas = _gate_rows(layout, deltas, S_up)
 
-        examples = steps * fed.batch_size
-        if fed.sim_mode == "flop_proportional":
-            k_count = np.asarray(S, np.float64).sum(1)
-            trained_fl = k_count * float(np.mean(layout.flops)) * examples * 3.0
-            fixed_fl = np.zeros(K)
-        else:  # fwd_aware
-            trained_fl = (np.asarray(S, np.float64) @ layout.flops
-                          ) * examples * 2.0
-            fixed_fl = np.full(K, task.forward_flops_per_example() * examples)
+        trained_fl, fixed_fl = T.cycle_flops(layout, fed, S,
+                                             task.forward_flops_per_example)
         upload = ((np.asarray(S_up, np.float64) @ layout.sizes)
                   * self._uplink_bytes_per_param)
         dur, t_comp, t_comm = completion_times(
@@ -637,8 +634,8 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                  fleet: FleetConfig, fed: AsyncFedConfig,
                  state: AsyncFedState, local_update: Any, plan: AllocPlan,
                  fstate: FleetState, population: PopulationModel | None,
-                 trace: AsyncTrace, history: dict,
-                 aggbuf: AG.CohortAggBuffer, proto: Any, server_step: Any):
+                 trace: AsyncTrace, history: dict, proto: Any,
+                 server_step: Any):
         self.task = task
         self.strategy = strategy
         self.fleet = fleet
@@ -650,7 +647,6 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
         self.population = population
         self.trace = trace
         self.history = history
-        self.aggbuf = aggbuf
         self.proto = proto
         self.server_step = server_step
         self.grad_mode = fed.grad_mode
@@ -714,16 +710,9 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                if (fed.churn_rate > 0.0 or fed.arrival_rate > 0.0) else None)
         lu = (make_local_update(task, fed, strategy.prox_mu)
               if fed.grad_mode != "none" else None)
-        aggbuf = AG.CohortAggBuffer(task.layout, trainable0,
-                                    impl=fed.agg_impl,
-                                    interpret=fed.agg_interpret,
-                                    robust=strategy.robust,
-                                    trim_frac=strategy.trim_frac,
-                                    krum_f=strategy.krum_f)
         return cls(task, strategy, fleet, fed, state, lu, plan,
                    FleetState.create(fleet.N), pop, trace, _history_init(),
-                   aggbuf, trainable0,
-                   make_server_step(task.layout, strategy, fed, aggbuf))
+                   trainable0, make_server_step(task.layout, strategy, fed))
 
     # -- client dispatch ------------------------------------------------------
 
@@ -750,15 +739,7 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
         sched = fed.modality_schedule
         live_mm = (sched.masks_at(now, idx) if sched is not None
                    else fleet.modality_mask[idx])
-        if sched is not None:  # live candidates, plan-static budgets
-            unaware = self.strategy.alloc in ("full", "magnitude", "depth")
-            S = allocate_rows(
-                self.plan, self.strategy, state, idx,
-                cand=None if unaware else layout.accessible(live_mm),
-                mandatory=(layout.mandatory(live_mm)
-                           if self.strategy.mandatory else None))
-        else:
-            S = allocate_rows(self.plan, self.strategy, state, idx)  # [B, G]
+        S = self._allocate(idx, live_mm)  # [B, G]
         fault = None
         if self.fx is not None:
             fault = self.fx.on_dispatch(idx)
@@ -817,15 +798,8 @@ class VectorizedAsyncFedRun(_ServerFlushMixin):
                     deltas)
             self._pend_loss[idx] = np.asarray(losses)
 
-        examples = steps * fed.batch_size
-        if fed.sim_mode == "flop_proportional":
-            k_count = np.asarray(S, np.float64).sum(1)
-            trained_fl = k_count * float(np.mean(layout.flops)) * examples * 3.0
-            fixed_fl = np.zeros(B)
-        else:  # fwd_aware
-            trained_fl = (np.asarray(S, np.float64) @ layout.flops
-                          ) * examples * 2.0
-            fixed_fl = np.full(B, task.forward_flops_per_example() * examples)
+        trained_fl, fixed_fl = T.cycle_flops(layout, fed, S,
+                                             task.forward_flops_per_example)
         upload = ((np.asarray(S_up, np.float64) @ layout.sizes)
                   * self._uplink_bytes_per_param)
         dur, t_comp, t_comm = T.cycle_times(

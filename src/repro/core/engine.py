@@ -435,21 +435,8 @@ class FedRun:
         state.mag_ema[touched] = (0.5 * state.mag_ema + 0.5 * mag)[touched]
 
         # --- system simulation (time / energy / comm)
-        examples = fed.local_epochs * fed.steps_per_epoch * fed.batch_size
-        if fed.sim_mode == "flop_proportional":
-            # the paper's Sec. VI-A3 simulator: per-group cost is the
-            # *profiled mean* tau_n (matching Eq. 7's uniform budgeting —
-            # Table III: V0/V2/V3 share identical budgets AND speedups), and
-            # compute is proportional to the trained groups only.
-            k_count = np.asarray(S, np.float64).sum(1)
-            trained_fl = k_count * float(np.mean(layout.flops)) * examples * 3.0
-            fixed_fl = np.zeros(N)
-        else:  # fwd_aware (paper Sec. VII): only the backward is maskable,
-            # the full-model forward is a fixed cost, and real per-group
-            # FLOPs replace the uniform profile.
-            sel_flops = np.asarray(S, np.float64) @ layout.flops
-            trained_fl = sel_flops * examples * 2.0
-            fixed_fl = np.full(N, task.forward_flops_per_example() * examples)
+        trained_fl, fixed_fl = T.cycle_flops(layout, fed, S,
+                                             task.forward_flops_per_example)
         upload = (np.asarray(S, np.float64) @ layout.sizes) * 4.0
         cost = T.simulate_round(fleet, participating, trained_fl, fixed_fl,
                                 upload, fed.t_overhead, fed.utilization)

@@ -67,7 +67,8 @@ def quantize_int8_stacked(tree: Any, error: Any | None = None
 
     One symmetric scale per (client, leaf) — scales leaves are [K] — so a
     whole dispatch batch quantizes in one vectorized shot; this is the
-    uplink layout ``CohortAggBuffer.push_quantized`` ingests natively.
+    uplink layout ``aggregation.cohort_reduce(..., scales=)`` ingests
+    natively.
     ``error`` ([K, ...] residual stack) carries per-client error feedback.
     Returns ``(qtree, scales, residual)`` like ``quantize_int8_ef``.
     """

@@ -7,8 +7,8 @@ full base model at batch 1. This engine instead:
 
 * keeps client adapters in an ``AdapterRegistry`` — one [L, A, din, r]
   stacked store per LoRA target, ingesting per-client blocks straight from
-  trainer output / ``CohortAggBuffer`` aggregates (no per-request weight
-  copies, no merge step);
+  trainer output / ``aggregation.cohort_reduce`` aggregates (no
+  per-request weight copies, no merge step);
 * runs **continuous batching**: requests join and leave the decode batch at
   step granularity. Admission prefalls the prompt into a fresh
   single-request cache and scatters that row into the shared paged
@@ -151,7 +151,7 @@ class AdapterRegistry:
     slicing ([L] leading axis) is untouched and the per-row gather happens
     inside the mdlora kernel. Registration writes one slot; eviction frees
     it. ``ingest_update`` applies a server-side delta (trainer /
-    CohortAggBuffer.finalize output with the same [L, din, r] leaf layout)
+    cohort_reduce output with the same [L, din, r] leaf layout)
     to a registered adapter in place — the serve path sees fresh weights on
     the next decode step without any repacking.
     """

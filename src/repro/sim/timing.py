@@ -17,6 +17,7 @@ fleet energy = sum over devices (Eq. analog of Fig. 8).
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Callable
 
 import numpy as np
 
@@ -53,6 +54,41 @@ def per_client_times(fleet: FleetConfig, trained_flops: np.ndarray,
     t_comm = (np.asarray(upload_bytes, np.float64) * 8.0
               / (fleet.bandwidth_mbps * 1e6))
     return t_comp, t_comm
+
+
+def cycle_flops(layout, fed, S: np.ndarray,
+                forward_flops_per_example: Callable[[], float]
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """[K] (trained_flops, fixed_flops) of one local-training cycle for the
+    clients whose trained groups are the rows of ``S`` [K, G].
+
+    ``layout.flops`` is the [G] per-example cost of each group and ``fed``
+    gives ``sim_mode`` and the examples a cycle trains on
+    (local_epochs x steps_per_epoch x batch_size).
+
+    flop_proportional  the paper's Sec. VI-A3 simulator: per-group cost is
+                       the profiled mean (matching Eq. 7's uniform
+                       budgeting — Table III: V0/V2/V3 share identical
+                       budgets AND speedups), and compute is proportional
+                       to the trained groups only (3x: forward, backward,
+                       update).
+    fwd_aware          paper Sec. VII: only the backward is maskable, the
+                       full-model forward is a fixed cost
+                       (``forward_flops_per_example()``, called only in
+                       this mode), and real per-group FLOPs replace the
+                       uniform profile.
+    """
+    examples = fed.local_epochs * fed.steps_per_epoch * fed.batch_size
+    K = len(S)
+    if fed.sim_mode == "flop_proportional":
+        k_count = np.asarray(S, np.float64).sum(1)
+        trained_fl = k_count * float(np.mean(layout.flops)) * examples * 3.0
+        fixed_fl = np.zeros(K)
+    else:  # fwd_aware
+        trained_fl = (np.asarray(S, np.float64) @ layout.flops
+                      ) * examples * 2.0
+        fixed_fl = np.full(K, forward_flops_per_example() * examples)
+    return trained_fl, fixed_fl
 
 
 def cycle_times(fleet: FleetConfig, idx: np.ndarray,

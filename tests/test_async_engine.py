@@ -1,5 +1,5 @@
 """Event-driven async runtime tests: sync parity, straggler decoupling,
-cohort freezing, and the streaming cohort-agg reduction."""
+cohort freezing, and the whole-cohort reduction of the server step."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,6 +27,16 @@ def setup():
                         cnn_ch=(8, 16))
     task, tr0 = MMTask.create(cfg, KEY)
     return ds, task, tr0
+
+
+@pytest.fixture(scope="module")
+def tx_task():
+    """Backbone 2 (layer-stacked encoder LoRA): its layout has all three
+    leaf classes — the fusion leaf, axis-0 group leaves, whole-group
+    leaves — where the CNN's has no axis-0 leaves."""
+    cfg = mm_config_for("pamap2", backbone="transformer", d_feat=8,
+                        d_fused=32, enc_layers=2, enc_d=16, enc_ff=32)
+    return MMTask.create(cfg, KEY)
 
 
 # ---------------------------------------------------------------------------
@@ -191,14 +201,20 @@ def test_staleness_discount_downweights_stale_clients(setup):
 
 
 # ---------------------------------------------------------------------------
-# streaming cohort-agg reduction
+# the cohort reduction
 # ---------------------------------------------------------------------------
 
 
-def test_streaming_chunks_match_one_shot(setup):
-    """CohortAggBuffer over arbitrary chunkings == the one-shot
-    weighted_combine + group_divergence reduction."""
-    _, task, tr0 = setup
+@pytest.mark.parametrize("codec", ["fp32", "int8"])
+def test_cohort_reduce_matches_oracles(tx_task, codec):
+    """cohort_reduce over a whole cohort == weighted_combine +
+    group_divergence, on every leaf class. An int8 cohort is checked
+    against the oracles over its dequantized stack, with the staleness
+    discount deferred into the reduction (W from defer_scale) and applied
+    up front for the oracle."""
+    from repro import dist
+
+    task, tr0 = tx_task
     layout = task.layout
     rng = np.random.default_rng(0)
     N = 6
@@ -208,30 +224,45 @@ def test_streaming_chunks_match_one_shot(setup):
     trained = jnp.asarray(rng.random((N, layout.G)) > 0.4, jnp.float32)
     mmask = jnp.asarray(rng.random((N, layout.n_modalities)) > 0.3,
                         jnp.float32)
-    W = AG.cohort_weights(layout, trained, mmask)
     C = trained
+    if codec == "fp32":
+        W = AG.cohort_weights(layout, trained, mmask)
+        ref_x, W_ref, kw = deltas, W, {}
+    else:
+        q, scales, _ = dist.quantize_int8_stacked(deltas)
+        staleness = jnp.asarray(rng.integers(0, 5, N), jnp.float32)
+        disc = AG.staleness_discounts(staleness, 0.5)
+        W = AG.cohort_weights(layout, trained, mmask, client_scale=disc,
+                              defer_scale=True)
+        W_ref = AG.cohort_weights(layout, trained, mmask, client_scale=disc)
+        ref_x = dist.dequantize_int8_stacked(q, scales)
+        deltas = q
+        kw = dict(scales=scales, staleness=staleness, exponent=0.5)
 
-    ref_agg = mdlora.weighted_combine(layout, deltas, W)
-    ref_d = DV.group_divergence(layout, deltas, C)
-
-    for chunks in ([slice(0, 6)], [slice(0, 2), slice(2, 5), slice(5, 6)]):
-        buf = AG.CohortAggBuffer(layout, tr0)
-        for sl in chunks:
-            buf.push(jax.tree.map(lambda x: x[sl], deltas), W[sl], C[sl])
-        agg, d, cnt = buf.finalize()
-        for a, b in zip(jax.tree.leaves(ref_agg), jax.tree.leaves(agg)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=1e-5)
-        np.testing.assert_allclose(np.asarray(d), np.asarray(ref_d),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_array_equal(np.asarray(cnt),
-                                      np.asarray(C.sum(0)))
+    ref_agg = mdlora.weighted_combine(layout, ref_x, W_ref)
+    ref_d = DV.group_divergence(layout, ref_x, C)
+    agg, d, cnt = AG.cohort_reduce(layout, deltas, W, C, **kw)
+    for a, b in zip(jax.tree.leaves(ref_agg), jax.tree.leaves(agg)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    d, ref_d = np.asarray(d), np.asarray(ref_d)
+    if codec == "fp32":
+        np.testing.assert_allclose(d, ref_d, rtol=1e-4, atol=1e-4)
+    else:
+        # in units of each group's largest squared client norm: d =
+        # E|x|^2 - |Ex|^2 cancels, and a one-member cohort whose int8
+        # scales are applied inside the reduction leaves ~10 eps of it
+        norms = np.asarray(jax.vmap(
+            lambda t: mdlora.group_norms(layout, t))(ref_x))
+        s = np.maximum(norms.max(0), 1e-12)
+        np.testing.assert_allclose(d / s, ref_d / s, rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(C.sum(0)))
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_push_quantized_matches_push_dequantized(setup, impl):
-    """Fused int8 ingest through the buffer == dequantizing client-side and
-    pushing fp32, with the staleness discount folded in (defer_scale)."""
+def test_quantized_reduce_matches_dequantized(setup, impl):
+    """Fused int8 ingest (cohort_reduce with ``scales``) == dequantizing
+    client-side and reducing fp32 (without ``scales``), with the staleness
+    discount folded in (defer_scale)."""
     from repro import dist
 
     _, task, tr0 = setup
@@ -253,14 +284,11 @@ def test_push_quantized_matches_push_dequantized(setup, impl):
     W_def = AG.cohort_weights(layout, trained, mmask, client_scale=disc,
                               defer_scale=True)
     C = trained
+    kw = dict(impl=impl, interpret=True)
 
-    ref = AG.CohortAggBuffer(layout, tr0, impl=impl, interpret=True)
-    ref.push(deq, W_full, C)
-    ref_agg, ref_d, ref_cnt = ref.finalize()
-
-    buf = AG.CohortAggBuffer(layout, tr0, impl=impl, interpret=True)
-    buf.push_quantized(q, scales, W_def, C, staleness=staleness, exponent=a)
-    agg, d, cnt = buf.finalize()
+    ref_agg, ref_d, ref_cnt = AG.cohort_reduce(layout, deq, W_full, C, **kw)
+    agg, d, cnt = AG.cohort_reduce(layout, q, W_def, C, scales=scales,
+                                   staleness=staleness, exponent=a, **kw)
 
     for x, y in zip(jax.tree.leaves(ref_agg), jax.tree.leaves(agg)):
         np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=1e-6)
@@ -294,16 +322,15 @@ def _eager_flush(run, deltas, S, staleness, mmask_rows):
                            & fresh[:, None], jnp.float32)
         W = AG.cohort_weights(layout, ones, jnp.ones_like(mmask), **wkw)
     C = jnp.asarray(layout.accessible(mmask_rows) & (S > 0), jnp.float32)
-    buf = AG.CohortAggBuffer(layout, run.proto, impl=fed.agg_impl,
-                             interpret=True, robust=strat.robust)
+    kw = dict(impl=fed.agg_impl, interpret=True, robust=strat.robust)
     if quant:
-        buf.push_quantized(deltas.q, deltas.scales, W, C,
-                           jnp.asarray(staleness, jnp.float32), a)
+        agg, d, cnt = AG.cohort_reduce(
+            layout, deltas.q, W, C, scales=deltas.scales,
+            staleness=jnp.asarray(staleness, jnp.float32), exponent=a, **kw)
         src = dist.dequantize_int8_stacked(deltas.q, deltas.scales)
     else:
-        buf.push(deltas, W, C)
+        agg, d, cnt = AG.cohort_reduce(layout, deltas, W, C, **kw)
         src = deltas
-    agg, d, cnt = buf.finalize()
     tr = jax.tree.map(lambda t, g: t + fed.server_lr * g, run.proto, agg)
     d, cnt = np.asarray(d), np.asarray(cnt)
     dbar = np.ones(layout.G) * 1e-6
@@ -374,7 +401,9 @@ def test_server_step_matches_eager_reduction(setup, codec, agg, a,
         tr0, stack, deltas.scales if codec == "int8" else None,
         (S * fresh[:, None]).astype(np.float32), mmask_rows,
         staleness.astype(np.float32), fresh,
-        bd=run.aggbuf.fusion_bd(stack, codec == "int8"))
+        bd=AG.fusion_block(layout, stack, quant=codec == "int8",
+                           impl=run.fed.agg_impl,
+                           interpret=run.fed.agg_interpret))
     np.testing.assert_array_equal(np.asarray(stats[1]), cnt)
 
 

@@ -1,8 +1,8 @@
 """Byzantine-robust cohort reducer tests (core/aggregation.py): hypothesis
 property tests for the array-level estimators (permutation invariance,
 mean agreement, breakdown boundedness, degenerate-trim/median equivalence)
-plus layout-level unit tests for robust_combine / Krum / the CohortAggBuffer
-robust modes and their strategies.py wiring."""
+plus layout-level unit tests for robust_combine / Krum / the robust modes of
+cohort_reduce and their strategies.py wiring."""
 import dataclasses
 
 import jax
@@ -184,7 +184,7 @@ def test_robust_combine_rejects_unknown_kind(cnn_task):
 
 
 # ---------------------------------------------------------------------------
-# CohortAggBuffer robust modes
+# cohort_reduce robust modes
 # ---------------------------------------------------------------------------
 
 
@@ -201,10 +201,8 @@ def test_buffer_robust_bounded_where_mean_diverges(cnn_task, robust):
     W, C = _full_WC(lay, 5)
 
     def agg_norm(robust_kind, d):
-        buf = AG.CohortAggBuffer(lay, tr, robust=robust_kind,
-                                 trim_frac=0.25, krum_f=1)
-        buf.push(d, W, C)
-        agg, div, cnt = buf.finalize()
+        agg, div, cnt = AG.cohort_reduce(lay, d, W, C, robust=robust_kind,
+                                         trim_frac=0.25, krum_f=1)
         return (np.sqrt(sum(float(jnp.sum(jnp.square(x)))
                             for x in jax.tree.leaves(agg))), np.asarray(div))
 
@@ -216,24 +214,9 @@ def test_buffer_robust_bounded_where_mean_diverges(cnn_task, robust):
     np.testing.assert_allclose(div_rob, div_mean, rtol=1e-4)
 
 
-def test_buffer_robust_requires_single_push(cnn_task):
-    task, tr = cnn_task
-    lay = task.layout
-    deltas = _stack(tr, 4, KEY)
-    W, C = _full_WC(lay, 4)
-    buf = AG.CohortAggBuffer(lay, tr, robust="median")
-    buf.push(deltas, W, C)
-    with pytest.raises(RuntimeError, match="one push"):
-        buf.push(deltas, W, C)
-    buf.reset()
-    buf.push(deltas, W, C)  # reset clears the guard
-    with pytest.raises(ValueError, match="robust"):
-        AG.CohortAggBuffer(lay, tr, robust="bogus")
-
-
 def test_buffer_robust_quantized_dequantizes_first(cnn_task):
-    """push_quantized under a robust mode falls back to dequantize + fp32
-    push (order statistics cannot stream over int8 codes): the aggregate
+    """An int8 cohort under a robust mode is dequantized first and reduced
+    in fp32 (order statistics cannot be taken over int8 codes): the aggregate
     equals robust_combine over the dequantized stack with the staleness
     discount folded into the weights, and divergence matches the mean
     mode's quantized stats."""
@@ -246,9 +229,8 @@ def test_buffer_robust_quantized_dequantizes_first(cnn_task):
     stale = jnp.asarray([0.0, 1.0, 2.0, 0.0, 3.0])
     a = 0.5
 
-    buf = AG.CohortAggBuffer(lay, tr, robust="median")
-    buf.push_quantized(q, scales, W, C, stale, a)
-    agg, div, cnt = buf.finalize()
+    qkw = dict(scales=scales, staleness=stale, exponent=a)
+    agg, div, cnt = AG.cohort_reduce(lay, q, W, C, robust="median", **qkw)
 
     disc = 1.0 / (1.0 + np.asarray(stale)) ** a
     ref = AG.robust_combine(lay, deq, W * jnp.asarray(disc)[:, None],
@@ -256,9 +238,7 @@ def test_buffer_robust_quantized_dequantizes_first(cnn_task):
     for x, y in zip(jax.tree.leaves(agg), jax.tree.leaves(ref)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
 
-    bufm = AG.CohortAggBuffer(lay, tr)
-    bufm.push_quantized(q, scales, W, C, stale, a)
-    _, div_mean, _ = bufm.finalize()
+    _, div_mean, _ = AG.cohort_reduce(lay, q, W, C, **qkw)
     np.testing.assert_allclose(np.asarray(div), np.asarray(div_mean),
                                rtol=1e-4)
 
@@ -282,8 +262,8 @@ def test_strategy_registry_exposes_robust_entries():
 
 def test_robust_strategy_end_to_end(cnn_task):
     """relief_median survives a sign-flip attack on a small fleet: the run
-    completes, the aggregate stays finite, and the buffer was built in
-    median mode."""
+    completes, the aggregate stays finite, and the run reduces in median
+    mode."""
     from repro.sim import FaultModel, make_fleet, scale_fleet
     ds = make_har_dataset("pamap2", windows_per_subject=40, seed=0)
     task, tr0 = cnn_task
@@ -295,7 +275,7 @@ def test_robust_strategy_end_to_end(cnn_task):
         task, tr0, relief_median(buffer_size=8),
         fleet, AsyncFedConfig(rounds=1, local_epochs=1, steps_per_epoch=1,
                               batch_size=4, eval_every=0, seed=0, faults=fm))
-    assert run.aggbuf.robust == "median"
+    assert run.strategy.robust == "median"
     hist = run.run(ds, total_updates=40)
     assert np.isfinite(hist["loss"]).all()
     assert all(np.isfinite(np.asarray(x)).all()

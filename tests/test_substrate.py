@@ -316,6 +316,37 @@ def test_simulate_round_straggler_and_energy():
     assert cost.per_device_idle_s[0] > cost.per_device_idle_s[2] - 1e-9
 
 
+@pytest.mark.parametrize("mode", ["flop_proportional", "fwd_aware"])
+def test_cycle_flops(mode):
+    """The client-cycle cost model against values worked by hand: 3 groups
+    of 10/20/30 FLOPs per example, 2 x 3 x 4 = 24 examples a cycle."""
+    from types import SimpleNamespace
+
+    from repro.sim.timing import cycle_flops
+
+    layout = SimpleNamespace(flops=np.array([10.0, 20.0, 30.0]))
+    fed = SimpleNamespace(sim_mode=mode, local_epochs=2, steps_per_epoch=3,
+                          batch_size=4)
+    S = np.array([[1, 0, 1], [0, 0, 0], [1, 1, 1]], bool)
+    forward_calls = []
+
+    def forward():
+        forward_calls.append(1)
+        return 100.0
+
+    trained, fixed = cycle_flops(layout, fed, S, forward)
+    if mode == "flop_proportional":
+        # trained groups x mean group cost (20) x 24 examples x 3
+        np.testing.assert_array_equal(trained, [2880.0, 0.0, 4320.0])
+        np.testing.assert_array_equal(fixed, [0.0, 0.0, 0.0])
+        assert not forward_calls  # the forward is not charged here
+    else:
+        # selected groups' own cost (40, 0, 60) x 24 examples x 2, plus the
+        # full forward (100) x 24 examples for every client
+        np.testing.assert_array_equal(trained, [1920.0, 0.0, 2880.0])
+        np.testing.assert_array_equal(fixed, [2400.0, 2400.0, 2400.0])
+
+
 def test_hetero_scaling():
     from repro.sim import make_fleet
 
